@@ -22,11 +22,7 @@ Measures, in the `bench_throughput` CSV idiom:
     asserted against the jnp oracle) — the ISSUE-5 acceptance row
     (planes must beat the PR-4 packed path) and the ISSUE-9 one
     (fusednet must beat the per-layer planes chain by >= 1.2x)
-  * the roofline gap (ISSUE 9): XLA `jit_cost` bytes/flops of the
-    fusednet megakernel vs its measured time — the bytes-bound time at
-    an assumed HBM bandwidth becomes the denominator of a tracked
-    gap-to-hardware ratio (`netgen_roofline_*` rows; enormous in
-    interpret mode on CPU, the point is the trend)
+  * XLA `jit_cost` bytes/flops of the jnp oracle (`netgen_serve_jit_cost_*`)
   * the persistent autotuner (ISSUE 5): `pallas[tuned=true]` grid
     search wall-clock, the winning (form, bm, bn, bkw), and the tuned
     predictor's timing next to the fixed-default forms
@@ -62,13 +58,6 @@ import tempfile
 import time
 
 import numpy as np
-
-# Roofline denominator: assumed HBM bandwidth of a TPU-class part. The
-# bytes-bound time `bytes_accessed / _HBM_GBPS` is a hardware floor, not
-# a CPU-interpret expectation — the measured/bound ratio it yields is
-# the tracked gap-to-hardware number (ROADMAP item 4).
-_HBM_GBPS = 900.0
-
 
 def _nets(m: int, sizes, seed: int = 0):
     from repro.core import quantize
@@ -428,7 +417,7 @@ def run(full: bool = False, json_path: str | None = None) -> list[str]:
         f"telemetry tracing overhead too high: on={dt_on*1e6:.1f}us "
         f"off={dt_off*1e6:.1f}us ({overhead*100:.1f}%)")
 
-    # -- roofline: XLA cost analysis vs measured (ISSUE 9) ------------------
+    # -- XLA cost analysis of the oracle -------------------------------------
     prof = telemetry.jit_cost(oracle.artifact, (pb, psizes[0]))
     if prof is not None:
         results["roofline_jit"] = {
@@ -436,31 +425,6 @@ def run(full: bool = False, json_path: str | None = None) -> list[str]:
         rows.append(f"netgen_serve_jit_cost_jnp,0,"
                     f"flops={prof['flops']:.0f};"
                     f"bytes={prof['bytes_accessed']:.0f}")
-    # the megakernel's gap-to-hardware row: measured time vs the
-    # bytes-bound floor its jit_cost implies at an assumed HBM
-    # bandwidth — persisted in BENCH_netgen.json so successive PRs
-    # track the ratio (interpret mode is orders of magnitude off the
-    # floor; the ratio's trend is the signal, not its magnitude)
-    fused_fn = forms["fusednet"].artifact
-    prof_f = telemetry.jit_cost(
-        getattr(fused_fn, "jitted", fused_fn), (pb, psizes[0]))
-    if prof_f is not None:
-        measured_us = results["packed"]["fusednet"]["us_per_batch"]
-        bound_us = prof_f["bytes_accessed"] / (_HBM_GBPS * 1e9) * 1e6
-        ratio = measured_us / bound_us if bound_us > 0 else float("inf")
-        results["roofline"] = {
-            "target": "pallas[fusednet=true]", "sizes": list(psizes),
-            "batch": pb, "flops": prof_f["flops"],
-            "bytes_accessed": prof_f["bytes_accessed"],
-            "hbm_gbps_assumed": _HBM_GBPS,
-            "bytes_bound_us": bound_us,
-            "measured_us": measured_us,
-            "measured_vs_bound": ratio,
-        }
-        rows.append(f"netgen_roofline_fusednet_b{pb},{measured_us:.0f},"
-                    f"bound_us={bound_us:.2f};ratio={ratio:.0f};"
-                    f"flops={prof_f['flops']:.0f};"
-                    f"bytes={prof_f['bytes_accessed']:.0f}")
 
     results["telemetry"] = telemetry.summary()
 

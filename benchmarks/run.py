@@ -22,9 +22,7 @@ Row conventions: ratio rows (`*_speedup`) put 0 in us_per_call and
 carry `ratio=..;<num>_us=..;<den>_us=..` in derived — the ratio's own
 measurement pair, self-contained in BENCH_netgen.json. The serve suite
 emits one `netgen_serve_pallas_<form>_b256` row per datapath (dense /
-packed / planes / fusednet) plus `netgen_roofline_fusednet_b256`:
-us_per_call is the measured time, derived holds the jit_cost-derived
-bytes-bound floor and the measured/bound ratio.
+packed / planes / fusednet).
 """
 from __future__ import annotations
 
@@ -111,6 +109,8 @@ def main() -> None:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.fake_devices}")
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (bench_kernels, bench_ladder, bench_netgen,
                             bench_netgen_engine, bench_netgen_explore,

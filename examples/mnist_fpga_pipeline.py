@@ -29,12 +29,15 @@ from pathlib import Path
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import dataset, mlp, quantize
 from repro import netgen
+from repro.kernels import resolve_interpret
 from repro.netgen import telemetry
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--deep", action="store_true",
@@ -122,6 +125,9 @@ def main():
           f"-> {args.verilog_out}")
 
     print("\n== specialized inference (exactness + throughput) ==")
+    # Pallas kernels run interpreted on the CPU: such a rate is no TPU speed
+    interp_note = ("  (Pallas interpret mode on the CPU, not TPU speed)"
+                   if resolve_interpret(None) else "")
     l3 = quantize.predict_l3(params)(jnp.asarray(xte))
     targets = ["jnp", "pallas", "pallas[tuned=true,planes=true]"]
     if not args.deep:
@@ -138,7 +144,7 @@ def main():
         form = f" form={art.plan_form}" if "tuned" in target else ""
         print(f"  target={target:30s} exact={exact} "
               f"{n/dt:,.0f} preds/s{form}"
-              + ("  (interpret-mode Python, not TPU speed)" if target != "jnp" else ""))
+              + (interp_note if target != "jnp" else ""))
     if session.tuner is not None:
         print(f"  {session.tuner.stats.row()}")
 

@@ -11,11 +11,13 @@ function, and verifies both are exact rewrites.
 import numpy as np
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import netgen, quantize
 from repro.core.ladder import run_ladder
 
 
 def main():
+    enable_compile_cache()
     print("== paper ladder (reduced size for speed; benchmarks run full) ==")
     r = run_ladder(n_train=600, n_test=400, epochs=30, seed=0,
                    backends=("jnp", "pallas"))

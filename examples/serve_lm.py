@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.compile_cache import enable_compile_cache
 from repro.data.pipeline import make_batch
 from repro.models import api, base
 from repro.quantized import apply as qapply
@@ -18,6 +19,7 @@ from repro.serve.engine import Engine, ServeConfig
 
 
 def main():
+    enable_compile_cache()
     cfg = configs.smoke("qwen1.5-4b")
     params = base.tree_init(api.abstract_params(cfg), jax.random.PRNGKey(0))
 

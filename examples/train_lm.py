@@ -11,6 +11,7 @@ multi-pod entry point.
 import argparse
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.models.base import ArchConfig, ShapeConfig
 from repro.optim import adamw
 from repro.train import trainer
@@ -32,6 +33,7 @@ CFG_100M = ArchConfig(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=4)

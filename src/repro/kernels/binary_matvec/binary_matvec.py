@@ -13,8 +13,8 @@ Three datapaths, in increasing bit-economy:
   * bitpacked uint32 (B, K//32)       — `binary_matmul_packed_kernel`
     (32 activations per word: 8x less HBM->VMEM traffic than int8; the
     TPU analogue of the paper's single-bit wires — but the weights
-    still travel as full int32 and the words are unpacked in-register
-    back to a (bm, bk, bn) select)
+    still travel as full int32 and each word is unpacked in-register
+    into a (bm, 32, bn) select)
   * fully bit-packed                  — `binary_matmul_planes_kernel`
     BOTH operands travel as bits: the int32 weight matrix is decomposed
     into signed bit-planes w = sum_b 2^b (pos_b - neg_b), each plane
@@ -32,12 +32,16 @@ Tiling: grid (B/bm, N/bn, K/bk) with the K axis innermost (sequential on
 TPU), accumulating into the output block, which stays resident in VMEM
 across the K sweep (revisited blocks are not re-fetched). Block sizes
 are keyword knobs on every entry point so `repro.netgen.tune` can
-search them per workload instead of trusting the defaults.
+search them per workload instead of trusting the defaults. On a TPU a
+block's last dim must be the whole padded dim or a multiple of 128
+lanes (`repro.netgen.analysis.tile_legality` rejects other tiles), so
+the word-tile default bkw=128 takes the whole K of any fan-in up to
+4096 bits.
 
 A fourth datapath, `binary_forward_planes`, fuses an ENTIRE planes-form
 network — every layer's bit-plane weights resident in VMEM at once —
 into one persistent launch: binarize+pack on entry, per-layer popcount
-accumulate, strict step + repack *in-register* between layers (the
+accumulate, strict step + repack *in-kernel* between layers (the
 inter-layer activations never touch HBM), argmax fused at the end. The
 grid runs over batch tiles only (and a leading model axis when the
 input is a stacked (M, B, K) block), so Pallas's grid pipeline
@@ -50,6 +54,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 # --------------------------------------------------------------------------
@@ -69,7 +75,7 @@ def _binary_matmul_kernel(x_ref, w_ref, o_ref):
     # Masked accumulate: select rows of w where the activation bit is set,
     # then reduce over k inside the tile. (bm, bk, bn) never materializes in
     # HBM — it is a VPU select feeding an add-reduce within VMEM.
-    sel = jnp.where(x[:, :, None] != 0, w[None, :, :], 0)
+    sel = jnp.where(x.astype(jnp.int32)[:, :, None] != 0, w[None, :, :], 0)
     o_ref[...] += jnp.sum(sel, axis=1)
 
 
@@ -81,7 +87,7 @@ def binary_matmul(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """y = x @ w with x in {0,1}. Pads to tile multiples; returns int32 (B, N)."""
     B, K = x.shape
@@ -101,7 +107,7 @@ def binary_matmul(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Bp, Np), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xp, wp)
     return out[:B, :N]
 
@@ -111,23 +117,30 @@ def binary_matmul(
 # --------------------------------------------------------------------------
 
 def _binary_matmul_packed_kernel(xp_ref, w_ref, o_ref, *, bkw: int):
-    """xp: (bm, bkw) uint32; w: (bkw*32, bn) int32; o: (bm, bn) int32."""
+    """xp: (bm, bkw) uint32; w: (bkw, 32, bn) int32, row j of word k
+    holding the weights of activation bit j; o: (bm, bn) int32."""
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    xp = xp_ref[...]                       # (bm, bkw)
-    w = w_ref[...]                         # (bkw*32, bn)
-    bm = xp.shape[0]
-    bn = w.shape[1]
-    # Unpack 32 bits per word in-register, then masked-accumulate.
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    bits = (xp[:, :, None] >> shifts[None, None, :]) & jnp.uint32(1)
-    bits = bits.reshape(bm, bkw * 32)      # (bm, bk) in {0,1}
-    sel = jnp.where(bits[:, :, None] != 0, w[None, :, :], 0)
-    o_ref[...] += jnp.sum(sel, axis=1)
+    xp = jax.lax.bitcast_convert_type(xp_ref[...], jnp.int32)  # (bm, bkw)
+    lane = jax.lax.broadcasted_iota(jnp.int32, xp.shape, 1)
+    shifts = jnp.arange(32, dtype=jnp.int32)
+
+    # One word per step: Mosaic cannot merge (bkw, 32) into one lane
+    # axis, so the select runs per word over its 32 weight rows. The
+    # word is picked by a masked lane sum (one nonzero term), which
+    # takes a loop index where a dynamic lane slice would not.
+    def word(c, acc):
+        col = jnp.sum(jnp.where(lane == c, xp, 0), axis=1, keepdims=True)
+        bits = (col >> shifts[None, :]) & 1             # (bm, 32)
+        sel = jnp.where(bits[:, :, None] != 0, w_ref[c][None], 0)
+        return acc + jnp.sum(sel, axis=1)
+
+    acc = jax.lax.fori_loop(0, bkw, word, jnp.zeros(o_ref.shape, jnp.int32))
+    o_ref[...] += acc
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bkw", "interpret"))
@@ -137,8 +150,8 @@ def binary_matmul_packed(
     *,
     bm: int = 128,
     bn: int = 128,
-    bkw: int = 8,          # K-tile in 32-bit words -> bk = 256 bits
-    interpret: bool = True,
+    bkw: int = 128,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """y = unpack(xp) @ w. xp: uint32 (B, K//32); w: (K, N) int32."""
     B, KW = xp.shape
@@ -150,17 +163,18 @@ def binary_matmul_packed(
     Bp, Np, KWp = _pad_to(B, bm), _pad_to(N, bn), _pad_to(KW, bkw)
     xpp = jnp.zeros((Bp, KWp), jnp.uint32).at[:B, :KW].set(xp)
     wp = jnp.zeros((KWp * 32, Np), jnp.int32).at[:K, :N].set(w.astype(jnp.int32))
+    wp = wp.reshape(KWp, 32, Np)
 
     out = pl.pallas_call(
         functools.partial(_binary_matmul_packed_kernel, bkw=bkw),
         grid=(Bp // bm, Np // bn, KWp // bkw),
         in_specs=[
             pl.BlockSpec((bm, bkw), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bkw * 32, bn), lambda i, j, k: (k, j)),
+            pl.BlockSpec((bkw, 32, bn), lambda i, j, k: (k, 0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Bp, Np), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xpp, wp)
     return out[:B, :N]
 
@@ -202,8 +216,8 @@ def binary_matmul_planes(
     *,
     bm: int = 128,
     bn: int = 128,
-    bkw: int = 8,          # K-tile in 32-bit words -> bk = 256 bits
-    interpret: bool = True,
+    bkw: int = 128,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """y = unpack(xp) @ w for w = sum_b 2^b (unpack(pos_b) - unpack(neg_b)).
 
@@ -234,7 +248,7 @@ def binary_matmul_planes(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Bp, Np), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xpp, posp, negp)
     return out[:B, :N]
 
@@ -244,26 +258,51 @@ def binary_matmul_planes(
 # --------------------------------------------------------------------------
 
 def _pack_bits_block(bits: jnp.ndarray, words: int) -> jnp.ndarray:
-    """In-register repack: bool (bm, n) -> uint32 words (bm, words),
-    zero-padding n up to words*32 (strict step: padding bits are 0)."""
-    bm, n = bits.shape
-    total = words * 32
-    if n < total:
-        bits = jnp.concatenate(
-            [bits, jnp.zeros((bm, total - n), bits.dtype)], axis=1)
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    b32 = bits.reshape(bm, words, 32).astype(jnp.uint32)
-    return jnp.sum(b32 << shifts[None, None, :], axis=-1, dtype=jnp.uint32)
+    """In-kernel repack: bool (bm, n) -> uint32 words (bm, words),
+    zero-padding n up to words*32 (strict step: padding bits are 0).
+
+    Mosaic cannot split the lane axis into (words, 32), so the packing
+    runs on the MXU: bit j of the row lands in word j // 32 through a
+    (n, words) matrix of powers of two, one matrix per 16-bit half. Each
+    half-word is a sum of distinct powers below 2^16, exact in bf16
+    operands with float32 accumulation."""
+    n = bits.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, words), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, words), 1)
+    off = row - col * 32                  # bit position inside word col
+    x = bits.astype(jnp.float32).astype(jnp.bfloat16)
+
+    def half(lo: int) -> jnp.ndarray:
+        inside = (off >= lo) & (off < lo + 16)
+        weight = jnp.where(inside, 1 << jnp.clip(off - lo, 0, 15), 0)
+        m = weight.astype(jnp.float32).astype(jnp.bfloat16)
+        return jnp.dot(x, m, preferred_element_type=jnp.float32
+                       ).astype(jnp.int32)
+
+    return jax.lax.bitcast_convert_type(
+        (half(16) << 16) | half(0), jnp.uint32)
+
+
+def argmax_lanes(scores: jnp.ndarray) -> jnp.ndarray:
+    """int32 (bm, n) -> (bm, 1) index of the first maximum, as
+    `jnp.argmax` picks it. Mosaic lowers argmax for float32 only, and a
+    float cast is inexact past 2^24, so take the max and then the least
+    index that attains it."""
+    best = jnp.max(scores, axis=-1, keepdims=True)
+    idx = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    return jnp.min(jnp.where(scores == best, idx, scores.shape[-1]),
+                   axis=-1, keepdims=True)
 
 
 def _forward_planes_kernel(x_ref, *refs, threshold: int, layers, n_classes: int,
                            bkw, stacked: bool):
     """One batch tile through the whole net. x: (bm, K) raw uint8 (leading
     model axis of size 1 when stacked); per layer l, refs hold pos_l then
-    neg_l uint32 (P_l, W_l, N_l) bit-planes, fully resident; o: (bm,) int32
-    predicted class. Activations live in registers/VMEM for the whole
-    sweep — the only HBM traffic per grid step is the input tile and the
-    (bm,) prediction vector."""
+    neg_l uint32 (P_l, W_l, N_l) bit-planes, fully resident; o: (bm, 1)
+    int32 predicted class (a column: Mosaic refuses a rank-1 block under
+    128 rows). Activations live in registers/VMEM for the whole sweep —
+    the only HBM traffic per grid step is the input tile and the
+    predictions."""
     o_ref = refs[-1]
     plane_refs = refs[:-1]
     x = x_ref[...]
@@ -292,8 +331,8 @@ def _forward_planes_kernel(x_ref, *refs, threshold: int, layers, n_classes: int,
             a = _pack_bits_block(acc > 0, out_words)
     # Slice to the real class count before argmax: a zero-padded class
     # column must never win when every real score is negative.
-    out = jnp.argmax(acc[:, :n_classes], axis=-1).astype(jnp.int32)
-    o_ref[...] = out[None, :] if stacked else out
+    out = argmax_lanes(acc[:, :n_classes])           # (bm, 1)
+    o_ref[...] = out[None] if stacked else out
 
 
 @functools.partial(
@@ -306,7 +345,7 @@ def binary_forward_planes(
     n_classes: int,
     bm: int = 32,
     bkw: int | None = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Whole-net forward in ONE pallas_call: raw uint8 images -> class ids.
 
@@ -360,11 +399,11 @@ def binary_forward_planes(
             kern,
             grid=(M, Bp // bm),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, bm), lambda m, i: (m, i)),
-            out_shape=jax.ShapeDtypeStruct((M, Bp), jnp.int32),
-            interpret=interpret,
+            out_specs=pl.BlockSpec((1, bm, 1), lambda m, i: (m, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((M, Bp, 1), jnp.int32),
+            interpret=resolve_interpret(interpret),
         )(xp, *planes)
-        return out[:, :B]
+        return out[:, :B, 0]
     xp = jnp.zeros((Bp, K), jnp.uint8).at[:B].set(x.astype(jnp.uint8))
     in_specs = [pl.BlockSpec((bm, K), lambda i: (i, 0))]
     for P, W, N, _ in layers:
@@ -374,11 +413,11 @@ def binary_forward_planes(
         kern,
         grid=(Bp // bm,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Bp,), jnp.int32),
-        interpret=interpret,
+        out_specs=pl.BlockSpec((bm, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
+        interpret=resolve_interpret(interpret),
     )(xp, *planes)
-    return out[:B]
+    return out[:B, 0]
 
 
 def _rup(x: int, m: int = 8) -> int:

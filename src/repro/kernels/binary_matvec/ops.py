@@ -1,4 +1,8 @@
-"""Public ops for the binary (multiplication-free) matmul kernel."""
+"""Public ops for the binary (multiplication-free) matmul kernel.
+
+Every kernel op takes `interpret=`; left unset, kernels run in Pallas
+interpret mode when JAX's backend is the CPU and compile through Mosaic
+on a TPU (`repro.kernels.resolve_interpret`)."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -6,20 +10,14 @@ import jax.numpy as jnp
 from repro.kernels.binary_matvec import binary_matvec as _k
 from repro.kernels.binary_matvec import ref as _ref
 
-# Default to interpret mode (this container is CPU-only); on a real TPU
-# deployment, set interpret=False via these wrappers.
-_INTERPRET = True
-
 
 def binary_matmul(x: jnp.ndarray, w: jnp.ndarray, **kw) -> jnp.ndarray:
     """y = x @ w, x in {0,1} (int8), w int — adds-only Pallas kernel."""
-    kw.setdefault("interpret", _INTERPRET)
     return _k.binary_matmul(x, w, **kw)
 
 
 def binary_matmul_packed(xp: jnp.ndarray, w: jnp.ndarray, **kw) -> jnp.ndarray:
     """y = unpack(xp) @ w for bitpacked activations (uint32 words)."""
-    kw.setdefault("interpret", _INTERPRET)
     return _k.binary_matmul_packed(xp, w, **kw)
 
 
@@ -27,7 +25,6 @@ def binary_matmul_planes(xp: jnp.ndarray, pos: jnp.ndarray,
                          neg: jnp.ndarray, **kw) -> jnp.ndarray:
     """y = unpack(xp) @ w for w decomposed into packed signed bit-planes
     (pos/neg uint32 (P, KW, N)) — the fully bit-packed popcount kernel."""
-    kw.setdefault("interpret", _INTERPRET)
     return _k.binary_matmul_planes(xp, pos, neg, **kw)
 
 
@@ -37,7 +34,6 @@ def binary_forward_planes(x: jnp.ndarray, *planes: jnp.ndarray,
     layer's resident bit-planes in ONE Pallas launch (binarize+pack,
     popcount accumulate, in-register step+repack, fused argmax). Plane
     arrays come from `ExecutionPlan.megakernel_view()`."""
-    kw.setdefault("interpret", _INTERPRET)
     return _k.binary_forward_planes(x, *planes, **kw)
 
 

@@ -19,15 +19,32 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+from repro.kernels.binary_matvec.binary_matvec import argmax_lanes
+
+# Each byte dot sums at most 255 * K, exact in float32 below 2^24.
+_MAX_FAN_IN = (1 << 24) // 255
+
+
+def _binary_dot(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """{0,1} (bm, K) @ int32 (K, N) -> int32, exact on the MXU. Mosaic
+    has no integer dot, so w is split into its four unsigned bytes, each
+    exact as a bf16 operand; every byte dot is exact in float32, and the
+    int32 recombination wraps exactly as an int32 matmul does."""
+    xb = x.astype(jnp.float32).astype(jnp.bfloat16)
+    acc = jnp.zeros((x.shape[0], w.shape[1]), jnp.int32)
+    for i in range(4):
+        byte = ((w >> (8 * i)) & 0xFF).astype(jnp.float32).astype(jnp.bfloat16)
+        d = jnp.dot(xb, byte, preferred_element_type=jnp.float32)
+        acc = acc + (d.astype(jnp.int32) << (8 * i))
+    return acc
+
 
 def _fused_mlp_kernel(x_ref, w1_ref, w2_ref, o_ref, *, threshold: int):
-    x = (x_ref[...].astype(jnp.int32) > threshold).astype(jnp.int32)  # (bm, K)
-    w1 = w1_ref[...]                                                  # (K, H)
-    w2 = w2_ref[...]                                                  # (H, O)
-    hi = jax.lax.dot(x, w1, preferred_element_type=jnp.int32)
-    ho = (hi > 0).astype(jnp.int32)                                   # MSB step
-    fi = jax.lax.dot(ho, w2, preferred_element_type=jnp.int32)
-    o_ref[...] = jnp.argmax(fi, axis=-1).astype(jnp.int32)
+    x = x_ref[...].astype(jnp.int32) > threshold                      # (bm, K)
+    hi = _binary_dot(x, w1_ref[...])                                  # (bm, H)
+    fi = _binary_dot(hi > 0, w2_ref[...])                             # MSB step
+    o_ref[...] = argmax_lanes(fi)                                     # (bm, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("threshold", "bm", "interpret"))
@@ -38,13 +55,14 @@ def fused_mlp_predict(
     *,
     threshold: int = 128,
     bm: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Predictions for a batch, whole net in one launch. Returns int32 (B,)."""
     B, K = x_uint8.shape
     K2, H = w1.shape
     H2, O = w2.shape
     assert K == K2 and H == H2, (x_uint8.shape, w1.shape, w2.shape)
+    assert max(K, H) <= _MAX_FAN_IN, (K, H)
     bm = min(bm, max(8, B))
     Bp = ((B + bm - 1) // bm) * bm
     xp = jnp.zeros((Bp, K), jnp.uint8).at[:B].set(x_uint8.astype(jnp.uint8))
@@ -57,8 +75,8 @@ def fused_mlp_predict(
             pl.BlockSpec((K, H), lambda i: (0, 0)),   # whole w1 resident
             pl.BlockSpec((H, O), lambda i: (0, 0)),   # whole w2 resident
         ],
-        out_specs=pl.BlockSpec((bm,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Bp,), jnp.int32),
-        interpret=interpret,
+        out_specs=pl.BlockSpec((bm, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
+        interpret=resolve_interpret(interpret),
     )(xp, w1.astype(jnp.int32), w2.astype(jnp.int32))
-    return out[:B]
+    return out[:B, 0]

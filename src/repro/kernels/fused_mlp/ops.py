@@ -5,11 +5,8 @@ import jax.numpy as jnp
 
 from repro.kernels.fused_mlp import fused_mlp as _k
 
-_INTERPRET = True
-
 
 def fused_mlp_predict(
     x_uint8: jnp.ndarray, w1: jnp.ndarray, w2: jnp.ndarray, *, threshold: int = 128, **kw
 ) -> jnp.ndarray:
-    kw.setdefault("interpret", _INTERPRET)
     return _k.fused_mlp_predict(x_uint8, w1, w2, threshold=threshold, **kw)

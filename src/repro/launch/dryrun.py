@@ -122,8 +122,6 @@ def build_lowered(cfg, shape, mesh, *, remat: str = "full",
 
 def _extract(compiled) -> dict:
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # jax <= 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     colls = rl.collective_stats(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),

@@ -11,13 +11,9 @@ import jax
 
 
 def make_mesh_compat(shape, axes) -> jax.sharding.Mesh:
-    """`jax.make_mesh` across jax versions: newer releases take (and for
-    explicit-sharding meshes need) `axis_types`; older ones (<= 0.4.x)
-    reject the kwarg and are implicitly Auto everywhere."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """`jax.make_mesh` with every axis Auto (implicit sharding)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

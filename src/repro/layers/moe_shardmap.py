@@ -154,13 +154,8 @@ def moe_shardmap(cfg: ArchConfig, p: dict, x: jnp.ndarray,
     in_specs = (xspec, P(None, None), P("model", None, None),
                 P("model", None, None), P("model", None, None))
     out_specs = (xspec, P(), P())
-    if hasattr(jax, "shard_map"):
-        mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False)
-    else:  # jax <= 0.4.x: experimental home, replication check named check_rep
-        from jax.experimental.shard_map import shard_map as _shard_map
-        mapped = _shard_map(body, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     out, lb, zl = mapped(x, p["router"].astype(jnp.float32),
                          p["wi"], p["wg"], p["wo"])
     return out, {"lb_loss": lb, "z_loss": zl}

@@ -746,6 +746,36 @@ def effective_tiles(plan: ExecutionPlan, form: str, blocks: Mapping,
     return tuple(tiles)
 
 
+# A TPU vreg holds 128 lanes: Mosaic takes a block whose last dim is the
+# whole (padded) array dim or a multiple of this, and refuses any other.
+TPU_LANES = 128
+
+
+def _lane_reason(plan: ExecutionPlan, form: str, blocks: Mapping,
+                 batch: int) -> str | None:
+    """Why the TPU compiler would refuse this candidate's lane tiling,
+    or None. In the per-layer kernels the K tile is the last dim of the
+    activation block (bits for dense, words for packed/planes) and the
+    fan-out tile the last dim of the weight and output blocks. The
+    fusednet megakernel keeps whole arrays resident and writes a
+    (bm, 1) column, so it tiles no lane dim."""
+    if form == "fusednet":
+        return None
+    fan_in = plan.n_inputs
+    tiles = effective_tiles(plan, form, blocks, batch)
+    for i, ((_, bn, bk), layer) in enumerate(zip(tiles, plan.layers)):
+        k_full = (fan_in if form == "dense"
+                  else max(-(-fan_in // PACK_LANES), 1))
+        for axis, tile, full in (("K", bk, k_full),
+                                 ("fan-out", bn, layer.fan_out)):
+            if tile < full and tile % TPU_LANES:
+                return (f"layer {i} {axis} tile {tile} is neither the "
+                        f"whole width {full} nor a multiple of "
+                        f"{TPU_LANES} lanes")
+        fan_in = layer.fan_out
+    return None
+
+
 # VMEM budget for the whole-net megakernel: everything it keeps resident
 # per grid step must fit one TPU core's vector memory (~16 MiB).
 FUSEDNET_VMEM_BYTES = 16 * 1024 * 1024
@@ -785,8 +815,9 @@ def tile_report(plan: ExecutionPlan, candidates: Sequence[Mapping], *,
                 batch: int, multi: bool = False
                 ) -> tuple[list, list]:
     """Split a candidate grid into (legal, rejected) where rejected is
-    [(candidate, reason), ...]: non-positive blocks, and clamp-
-    duplicates of an earlier candidate (searching both wastes a
+    [(candidate, reason), ...]: non-positive blocks, lane tiles the TPU
+    compiler refuses, fusednet residency over the VMEM budget, and
+    clamp-duplicates of an earlier candidate (searching both wastes a
     measurement on the same kernel)."""
     legal: list = []
     rejected: list = []
@@ -810,6 +841,9 @@ def _tile_reason(plan: ExecutionPlan, cand: Mapping, *, batch: int,
     blocks = {k: cand.get(k) for k in ("bm", "bn", "bkw")}
     if any(v is None for v in blocks.values()):
         return None                      # partial candidate: cannot judge
+    lanes = _lane_reason(plan, form, blocks, batch)
+    if lanes is not None:
+        return f"{form}: {lanes} (the TPU compiler refuses it)"
     if form == "fusednet":
         need = fusednet_vmem_bytes(
             plan, bm=int(blocks["bm"]), bkw=int(blocks["bkw"]), batch=batch)

@@ -44,8 +44,10 @@ single-launch `fused_mlp` kernel, the combinational-circuit analogue
 (one "net" per prediction, intermediate activations never leaving
 VMEM); `fused[tuned=true]` searches its batch tile.
 
-Kernels run in interpret mode on CPU containers (see kernels/*/ops.py);
-on a real TPU the same code path compiles to Mosaic.
+Kernels run in Pallas interpret mode where JAX's backend is the CPU (the
+tests, under `JAX_PLATFORMS=cpu`) and compile through Mosaic on a TPU
+(`repro.kernels.resolve_interpret`); `python chip_smoke.py` runs the
+compiled kernels on the chip.
 """
 from __future__ import annotations
 
@@ -64,14 +66,18 @@ _FORMS = ("dense", "packed", "planes")
 # (which runs the planes form, but as one persistent launch).
 _DATAPATHS = ("dense", "packed", "planes", "fusednet")
 
-# The tuner's default candidate grid: block sizes the binary_matvec
-# kernels accept, small enough to search in seconds yet covering the
-# batch/fan-out/reduction trade-offs that actually move the needle.
-_TUNE_BLOCKS = (
+# The candidate grid of the tuner and the explorer. The TPU compiler
+# takes a lane tile only when it is the whole width or a multiple of 128
+# (`analysis.tile_legality` rejects the rest before measuring), so
+# bkw=128 is the packed/planes word tile (the whole K of any fan-in up
+# to 4096 bits) and bkw=8 the dense kernel's 256-bit K tile and the
+# megakernel's in-register word chunk. Every datapath keeps a legal
+# candidate at every width.
+TUNE_BLOCKS = (
+    {"bm": 128, "bn": 128, "bkw": 128},
+    {"bm": 256, "bn": 128, "bkw": 128},
+    {"bm": 128, "bn": 256, "bkw": 128},
     {"bm": 128, "bn": 128, "bkw": 8},
-    {"bm": 128, "bn": 128, "bkw": 16},
-    {"bm": 64, "bn": 128, "bkw": 8},
-    {"bm": 128, "bn": 64, "bkw": 8},
 )
 _TUNE_BATCH = 256        # measurement batch: the serve layer's default cap
 
@@ -370,7 +376,7 @@ def _tuned_params(plan: ExecutionPlan, kw: dict, blocks: dict,
     candidates = []
     seen = set()
     for form in forms:
-        for grid in _TUNE_BLOCKS:
+        for grid in TUNE_BLOCKS:
             cand = {"form": form, **grid, **pinned}
             key = tuple(sorted(cand.items()))
             if key not in seen:
@@ -477,12 +483,12 @@ def compile_pallas(circuit: Circuit, *, interpret: bool | None = None,
     """Return a jitted fn chaining one kernel launch per plan layer —
     or, with `fusednet=true`, ONE whole-net megakernel launch.
 
-    `interpret` overrides the kernel ops' container default (interpret
-    mode on CPU); pass `pallas[interpret=false]` on a real TPU to lower
-    through Mosaic. `packed` selects the end-to-end bit-packed
-    activation datapath, `planes` the fully bit-packed (bit-plane
-    weight) datapath, `fusednet` the single-launch planes-form
-    megakernel — all bit-exact with dense. `bm`/`bn`/`bkw` pin kernel
+    `interpret` pins Pallas interpret mode on or off; left unset, the
+    kernels are interpreted only where JAX's backend is the CPU and
+    compile through Mosaic on a TPU. `packed` selects the end-to-end
+    bit-packed activation datapath, `planes` the fully bit-packed
+    (bit-plane weight) datapath, `fusednet` the single-launch
+    planes-form megakernel — all bit-exact with dense. `bm`/`bn`/`bkw` pin kernel
     block sizes; `tuned` grid-searches unpinned block sizes (and the
     datapath, when none is forced) through the persistent autotuner.
     The returned fn carries `.plan_form`, `.datapath` and `.blocks`

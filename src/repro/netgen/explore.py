@@ -69,6 +69,7 @@ from typing import Callable, Mapping
 import numpy as np
 
 from repro.netgen import telemetry
+from repro.netgen.backends.pallas import TUNE_BLOCKS
 from repro.netgen.graph import IrregularCircuitError
 from repro.netgen.pipeline import PipelineSpec
 from repro.netgen.plan import lower_circuit
@@ -91,12 +92,6 @@ _DEFAULT_PIPELINES = (
     "zeros,prune,addends,cse[bucketed=true]",
 )
 _DEFAULT_FORMS = ("dense", "packed", "planes", "fusednet")
-_DEFAULT_TILES = (
-    {"bm": 128, "bn": 128, "bkw": 8},
-    {"bm": 128, "bn": 128, "bkw": 16},
-    {"bm": 64, "bn": 128, "bkw": 8},
-    {"bm": 128, "bn": 64, "bkw": 8},
-)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,7 +134,7 @@ class SearchSpace:
     `nets` are names into the explorer's nets mapping."""
     pipelines: tuple = _DEFAULT_PIPELINES
     forms: tuple = _DEFAULT_FORMS
-    tiles: tuple = _DEFAULT_TILES
+    tiles: tuple = TUNE_BLOCKS
     nets: tuple = ("net",)
 
     def __post_init__(self):

@@ -189,8 +189,9 @@ class ExecutionPlan:
         """The whole-net megakernel's flattened view of this plan: the
         planes form with each hidden layer's fan_out zero-padded up to
         the NEXT layer's word width (N_l == W_{l+1} * 32), so the
-        in-kernel step+repack between layers is a pure reshape with no
-        bit shuffling. Zero-width layers are padded to one zero word.
+        in-kernel step+repack between layers sends bit j straight to
+        word j // 32 with no bit shuffling. Zero-width layers are
+        padded to one zero word.
         Padding is exact under strict-step semantics: a padded
         accumulator column is 0, step(0) = 0, and the padded bit lands
         in a zero-padded weight word of the next layer (zero popcount).

@@ -385,8 +385,7 @@ def _shard_stacked(fn, mesh, capacity: int):
     `shard_map` over the mesh's data axes, splitting the slot (batch)
     dimension — each device serves cap / n_data rows of every version.
     Returns None (single-device fallback) when the mesh has no data
-    axis or the capacity does not divide across it. Mirrors
-    `repro.layers.moe_shardmap`'s jax-version compat."""
+    axis or the capacity does not divide across it."""
     import jax
 
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
@@ -401,13 +400,8 @@ def _shard_stacked(fn, mesh, capacity: int):
     ax = data_axes if len(data_axes) > 1 else data_axes[0]
     in_specs = (P(None, ax, None),)
     out_specs = P(None, ax)
-    if hasattr(jax, "shard_map"):
-        mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False)
-    else:  # jax <= 0.4.x: experimental home, replication check named check_rep
-        from jax.experimental.shard_map import shard_map as _shard_map
-        mapped = _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False)
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     wrapped = jax.jit(mapped)
     # keep the datapath identity visible on the sharded wrapper: the
     # kernel span's form/launches attrs come from these
@@ -727,9 +721,9 @@ class NetServer:
 
         A set that cannot stack is no longer a silent fallback: the
         static diagnosis (`repro.netgen.analysis.diagnose_stack`, or
-        the build error when compilation itself fails) is recorded as a
-        `StackReport` readable through `stack_report()` and counted in
-        `netgen_stack_incompat_total{reason}`."""
+        the irregular-circuit / plan-verification error of the build) is
+        recorded as a `StackReport` readable through `stack_report()`
+        and counted in `netgen_stack_incompat_total{reason}`."""
         from repro.netgen import analysis
         from repro.parallel.sharding import active_mesh
 
@@ -770,7 +764,11 @@ class NetServer:
                         entry = ((sharded_fn, True) if sharded_fn is not None
                                  else (fn, False))
                         report = None
-                    except (IrregularCircuitError, ValueError) as e:
+                    except (IrregularCircuitError,
+                            analysis.VerificationError) as e:
+                        # only a plan the stacked form cannot take falls
+                        # back; a kernel that fails to lower or compile
+                        # is an error the caller must see
                         entry = (None, False)
                         report = analysis.StackReport(
                             compatible=False, n_versions=len(names),

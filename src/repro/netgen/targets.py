@@ -8,7 +8,7 @@ used by the serving layer. Targets are addressed by the same
 `name[opt=value,...]` item syntax as pipeline passes:
 
     jnp                      jitted adds-only predictor (the oracle)
-    pallas[interpret=false]  per-layer binary_matvec TPU kernel chain
+    pallas                   per-layer binary_matvec TPU kernel chain
     fused                    single-launch whole-net kernel (2-layer)
     verilog[style=legacy]    the paper's combinational module source
     cost                     IR walk -> logic-cell estimate vs Figure 7
@@ -183,7 +183,8 @@ register_target(Target(
 register_target(Target(
     name="pallas", kind="callable",
     description="per-layer binary_matvec TPU kernel chain "
-                "(interpret-mode on CPU; packed=true chains bit-packed "
+                "(compiled through Mosaic on a TPU, interpreted where "
+                "JAX's backend is the CPU; packed=true chains bit-packed "
                 "activations end to end, planes=true additionally "
                 "decomposes weights into packed bit-planes accumulated "
                 "by popcount, fusednet=true runs the whole planes-form "

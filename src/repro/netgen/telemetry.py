@@ -606,8 +606,6 @@ def jit_cost(fn, shape, dtype="uint8") -> dict | None:
         cost = lowered.compile().cost_analysis()
     except Exception:  # noqa: BLE001 — absent jax/lower/analysis all degrade
         return None
-    if isinstance(cost, (list, tuple)):     # jax <= 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     if not isinstance(cost, dict):
         return None
     return {"flops": float(cost.get("flops", 0.0)),

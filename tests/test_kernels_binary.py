@@ -218,3 +218,32 @@ def test_binary_forward_planes_property(b, n_in, n_h, n_out, mag, seed):
         jnp.asarray(x), *[jnp.asarray(p) for p in view.arrays],
         threshold=net.input_threshold, n_classes=view.n_classes))
     np.testing.assert_array_equal(got, want)
+
+
+def test_resolve_interpret_follows_platform():
+    """Interpret mode only where JAX's backend is the CPU; an explicit
+    flag always wins."""
+    import jax
+    from repro.kernels import resolve_interpret
+
+    assert resolve_interpret(None) == (jax.default_backend() == "cpu")
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
+
+
+@pytest.mark.parametrize("mag", [5, 1 << 12, 1 << 24])
+def test_fused_mlp_exact_for_wide_int32_weights(mag):
+    """The fused kernel's integer dots run as four exact byte dots on the
+    MXU: every byte of a negative or large weight must reach the sum."""
+    from repro.kernels.fused_mlp import ops as fused
+
+    rng = np.random.default_rng(mag)
+    w1 = rng.integers(-mag, mag + 1, size=(70, 33)).astype(np.int32)
+    w2 = rng.integers(-mag, mag + 1, size=(33, 6)).astype(np.int32)
+    x = rng.integers(0, 256, size=(40, 70)).astype(np.uint8)
+    a = (x.astype(np.int64) > 128).astype(np.int64)
+    hidden = (a @ w1).astype(np.int32) > 0          # int32 wraps as the kernel
+    want = np.argmax((hidden.astype(np.int64) @ w2).astype(np.int32), axis=-1)
+    got = np.asarray(fused.fused_mlp_predict(
+        jnp.asarray(x), jnp.asarray(w1), jnp.asarray(w2), threshold=128))
+    np.testing.assert_array_equal(got, want)

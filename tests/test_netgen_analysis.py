@@ -349,7 +349,7 @@ def test_verify_plan_catches_broken_chain():
 
 def _grid():
     return [{"bm": bm, "bn": bn, "bkw": bkw}
-            for bm in (8, 64) for bn in (8, 64) for bkw in (1, 8)]
+            for bm in (8, 64) for bn in (16, 64) for bkw in (1, 8)]
 
 
 def test_tuner_legality_skips_duplicates_same_winner():
@@ -396,10 +396,50 @@ def test_tile_legality_keeps_partial_and_distinct_candidates():
     c = _optimized(14, sizes=(40, 16, 4))
     plan = lower_circuit(c, form="dense")
     legal = tile_legality(plan, batch=64)
-    assert legal({"bm": 8, "bn": 8, "bkw": 1}) is None
-    assert legal({"bm": 16, "bn": 8, "bkw": 1}) is None   # distinct tiles
-    assert "duplicate" in legal({"bm": 8, "bn": 8, "bkw": 1})
+    assert legal({"bm": 8, "bn": 16, "bkw": 2}) is None
+    assert legal({"bm": 16, "bn": 16, "bkw": 2}) is None  # distinct tiles
+    assert "duplicate" in legal({"bm": 8, "bn": 16, "bkw": 2})
     assert legal({"form": "dense"}) is None               # partial: keep
+
+
+@pytest.mark.parametrize("form,blocks,refused", [
+    # 784 inputs pack into KW=25 words: a word tile is all 25 or a
+    # multiple of 128, never 8 or 16
+    ("packed", {"bm": 128, "bn": 128, "bkw": 8}, "K tile 8"),
+    ("planes", {"bm": 128, "bn": 128, "bkw": 16}, "K tile 16"),
+    ("planes", {"bm": 128, "bn": 128, "bkw": 25}, None),
+    ("packed", {"bm": 128, "bn": 128, "bkw": 128}, None),
+    # dense tiles K in bits: 8 words = 256 bits is a lane multiple
+    ("dense", {"bm": 128, "bn": 128, "bkw": 8}, None),
+    ("dense", {"bm": 128, "bn": 128, "bkw": 2}, "K tile 64"),
+    # a fan-out tile of 64 splits the 500-unit hidden layer
+    ("planes", {"bm": 128, "bn": 64, "bkw": 128}, "fan-out tile 64"),
+    # the megakernel keeps whole arrays resident: no lane tile to refuse
+    ("fusednet", {"bm": 32, "bn": 64, "bkw": 8}, None),
+])
+def test_tile_legality_tpu_lane_rule_at_paper_width(form, blocks, refused):
+    """The TPU compiler takes a block's last dim only when it is the
+    whole padded dim or a multiple of 128 lanes; the legality check
+    refuses the rest before any measurement."""
+    plan = lower_circuit(_optimized(19, sizes=(784, 500, 10)))
+    reason = tile_legality(plan, batch=256)({"form": form, **blocks})
+    if refused is None:
+        assert reason is None
+    else:
+        assert reason is not None and refused in reason
+        assert "multiple of 128" in reason
+
+
+@pytest.mark.parametrize("multi", [False, True])
+def test_tune_grid_keeps_a_legal_candidate_per_datapath(multi):
+    from repro.netgen.backends.pallas import _DATAPATHS, TUNE_BLOCKS
+
+    plan = lower_circuit(_optimized(19, sizes=(784, 500, 10)))
+    batch = 64 if multi else 256
+    for form in _DATAPATHS:
+        legal = tile_legality(plan, batch=batch, multi=multi)
+        kept = [b for b in TUNE_BLOCKS if legal({"form": form, **b}) is None]
+        assert kept, form
 
 
 def test_fusednet_vmem_matches_view_estimate():

@@ -334,6 +334,26 @@ def test_netserver_fallback_on_incompatible_topologies():
     np.testing.assert_array_equal(out["d"], _ref(deep, x))
 
 
+def test_netserver_stacked_build_error_reaches_caller(monkeypatch):
+    """A kernel the compiler refuses (Pallas raises its TPU lowering
+    refusals as ValueError) must fail the request, not turn into a
+    per-version fallback that hides the device path."""
+    from repro.netgen import serve as serve_mod
+
+    def refuse(*args, **kwargs):
+        raise ValueError("block shape refused by the TPU lowering")
+
+    server = netgen.NetServer(slot_capacity=8, warmup=False)
+    server.register("a", _random_net(82))
+    server.register("b", _random_net(83))
+    monkeypatch.setattr(serve_mod, "compile_multi", refuse)
+    x = _images(82, 8, 12)
+    with pytest.raises(ValueError, match="refused by the TPU lowering"):
+        server.predict_many({"a": x, "b": x})
+    assert server.dispatch_counts["fallback"] == 0
+    assert server.stack_report() == {}
+
+
 def test_netserver_shares_cache_across_servers():
     """A second server over the same cache acquires predictors warm."""
     cache = netgen.CompileCache()

@@ -86,3 +86,29 @@ def test_w8_served_lm_matches_quality(tmp_path):
     out_q = Engine(cfg, qp, sc).generate(prompts)
     agree = (out_fp == out_q).mean()
     assert agree >= 0.5, agree            # random-init logits are near-ties
+
+
+def test_compile_cache_dir_env_or_fixed_checkout_path(monkeypatch, tmp_path):
+    """Entry points keep JAX's persistent cache where
+    JAX_COMPILATION_CACHE_DIR says, setting no other location; without
+    it, in one fixed, gitignored directory of the checkout."""
+    from pathlib import Path
+
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.compile_cache import CHECKOUT_CACHE_DIR, enable_compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", "unchanged")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == "unchanged"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(CHECKOUT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        compilation_cache.reset_cache()
+    root = Path(__file__).resolve().parents[1]
+    assert CHECKOUT_CACHE_DIR == root / ".jax_cache"
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
