@@ -33,7 +33,12 @@ from above). Skipped when the trace carries no fusednet traffic.
   PYTHONPATH=src python benchmarks/check_trace.py DIR \\
       [--compile-budget-s 300]
 
-A fourth check (`check_explore`) gates the design-space explorer's
+A fourth check (`check_rounds`) gates the slot round's split: every
+`netgen.kernel` span has exactly one `netgen.round.launch` child (the
+predictor call) and one `netgen.round.fetch` child (the blocking copy
+of its result), the spans the benchmark's per-round metrics read.
+
+A fifth check (`check_explore`) gates the design-space explorer's
 counting identities when a trace carries explorer traffic: per
 explorer scope, `netgen_explore_candidates_total` ==
 `..._pruned_total` + `..._measured_total` (every considered candidate
@@ -42,8 +47,8 @@ was either statically rejected or measured) and
 candidate is backed by exactly one store artifact).
 
 The checks are importable pure functions (`check_spans`,
-`check_metrics`, `check_launches`, `check_explore`) so the telemetry
-tests exercise the same gate CI runs.
+`check_metrics`, `check_launches`, `check_rounds`, `check_explore`) so
+the telemetry tests exercise the same gate CI runs.
 """
 from __future__ import annotations
 
@@ -231,6 +236,30 @@ def check_launches(spans: list[dict],
     return errors
 
 
+ROUND_CHILDREN = ("netgen.round.launch", "netgen.round.fetch")
+
+
+def check_rounds(spans: list[dict]) -> list[str]:
+    """The slot round's split (empty list == pass): every
+    `netgen.kernel` span parents exactly one `netgen.round.launch` and
+    one `netgen.round.fetch` span."""
+    children: dict = defaultdict(lambda: dict.fromkeys(ROUND_CHILDREN, 0))
+    for rec in spans:
+        if rec.get("name") in ROUND_CHILDREN:
+            children[rec.get("parent_id")][rec["name"]] += 1
+    errors: list[str] = []
+    for rec in spans:
+        if rec.get("name") != "netgen.kernel":
+            continue
+        got = children[rec.get("span_id")]
+        for name in ROUND_CHILDREN:
+            if got[name] != 1:
+                errors.append(
+                    f"netgen.kernel span (span_id={rec.get('span_id')}) "
+                    f"has {got[name]} {name} children, expected exactly 1")
+    return errors
+
+
 def check_explore(samples: list[tuple[str, dict, float]]) -> list[str]:
     """The design-space explorer's counting identities (empty list ==
     pass), per `explorer=` scope: every unique candidate considered was
@@ -301,6 +330,7 @@ def check_trace_dir(trace_dir, *, compile_budget_s: float = 300.0
         errors += check_spans(spans, compile_budget_s=compile_budget_s,
                               require=require)
         errors += check_launches(spans, samples)
+        errors += check_rounds(spans)
     return errors
 
 

@@ -229,7 +229,17 @@ histograms have exact nearest-rank `p50/p95/p99`), `span(name, **attrs)`
 `new_scope(prefix)` (per-instance label), `get_registry()`. The traced
 span tree and metric names are documented in the telemetry module
 docstring; `examples/mnist_fpga_pipeline.py --trace DIR` shows the
-whole thing end to end.
+whole thing end to end. The serving path's spans split a slot round
+into `netgen.round.stage` (padding the slot block), then under
+`netgen.kernel` `netgen.round.launch` (the predictor call) and
+`netgen.round.fetch` (the blocking result copy); the engine's batcher
+adds `netgen.engine.form` (waiting for and forming a batch),
+`netgen.engine.admit` (deadlines, grouping, stacking its rows) and
+`netgen.engine.resolve` (setting its futures) beside
+`netgen.engine.batch`. While tracing is on each span is also a
+`jax.profiler.TraceAnnotation`, so a profiler trace shows it on the
+profiler's clock. `benchmarks/bench_netgen_serve.py` measures tracing on
+against off on the host; it asserts nothing about the off path.
 
 `repro.core.netgen` remains as a thin compatibility shim with the old
 `specialize` / `emit_verilog` / `prune` / `stats` names.

@@ -47,7 +47,10 @@ Telemetry (all labelled `engine=<scope>`, alongside the server's own
     netgen_engine_queue_depth          (gauge, post-admission)
     netgen_engine_queue_wait_seconds   (histogram, dequeue - enqueue)
     netgen_engine_batch_rows           (histogram, rows per dispatch)
+    netgen.engine.form                 (span: waiting for and forming a batch)
+    netgen.engine.admit                (span: deadlines, grouping, stacking)
     netgen.engine.batch                (span around each dispatch)
+    netgen.engine.resolve              (span: resolving the batch's futures)
 
     engine = netgen.Session(store=...).engine(slot_capacity=256,
                                               max_batch_delay=0.002)
@@ -168,7 +171,8 @@ class _EngineCore:
 
     def loop(self) -> None:
         while True:
-            batch = self._next_batch()
+            with self.tel.span("netgen.engine.form", engine=self.scope):
+                batch = self._next_batch()
             if batch is None:
                 return
             self._serve(batch)
@@ -218,29 +222,31 @@ class _EngineCore:
         Expired deadlines are rejected here — after queueing, before
         kernel work — and a dispatch failure fails only this batch's
         futures, never the batcher thread."""
-        now = time.perf_counter()
-        live: list[_Request] = []
-        for req in batch:
-            self.h_queue_wait.observe(now - req.t_enqueue)
-            if req.deadline is not None and now > req.deadline:
-                self.c_rejected["deadline"].inc()
-                if not req.future.cancelled():
-                    req.future.set_exception(DeadlineExceededError(
-                        f"deadline exceeded after "
-                        f"{now - req.t_enqueue:.4f}s in queue"))
-                continue
-            if not req.future.set_running_or_notify_cancel():
-                continue                     # caller cancelled while queued
-            live.append(req)
-        if not live:
-            return
-        by_version: "dict[str, list[_Request]]" = {}
-        for req in live:
-            by_version.setdefault(req.version, []).append(req)
-        xs = {v: stack_requests([r.x for r in rs])
-              for v, rs in by_version.items()}
-        self.c_batches.inc()
-        self.h_batch_rows.observe(len(live))
+        with self.tel.span("netgen.engine.admit", engine=self.scope,
+                           rows=len(batch)):
+            now = time.perf_counter()
+            live: list[_Request] = []
+            for req in batch:
+                self.h_queue_wait.observe(now - req.t_enqueue)
+                if req.deadline is not None and now > req.deadline:
+                    self.c_rejected["deadline"].inc()
+                    if not req.future.cancelled():
+                        req.future.set_exception(DeadlineExceededError(
+                            f"deadline exceeded after "
+                            f"{now - req.t_enqueue:.4f}s in queue"))
+                    continue
+                if not req.future.set_running_or_notify_cancel():
+                    continue                 # caller cancelled while queued
+                live.append(req)
+            if not live:
+                return
+            by_version: "dict[str, list[_Request]]" = {}
+            for req in live:
+                by_version.setdefault(req.version, []).append(req)
+            xs = {v: stack_requests([r.x for r in rs])
+                  for v, rs in by_version.items()}
+            self.c_batches.inc()
+            self.h_batch_rows.observe(len(live))
         try:
             with self.tel.span("netgen.engine.batch", engine=self.scope,
                                versions=len(xs), rows=len(live)):
@@ -249,9 +255,11 @@ class _EngineCore:
             for req in live:
                 req.future.set_exception(e)
             return
-        for v, rs in by_version.items():
-            for req, p in zip(rs, preds[v]):
-                req.future.set_result(int(p))
+        with self.tel.span("netgen.engine.resolve", engine=self.scope,
+                           rows=len(live)):
+            for v, rs in by_version.items():
+                for req, p in zip(rs, preds[v]):
+                    req.future.set_result(int(p))
         self.c_completed.inc(len(live))
 
     # -- shutdown ------------------------------------------------------------

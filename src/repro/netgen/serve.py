@@ -679,15 +679,20 @@ class NetServer:
         actually requested, run the jitted multi-net fn. Returns the
         (M, cap) predictions and the per-version valid row counts."""
         cap = self.slot_capacity
-        block = np.zeros((len(chunks), cap, chunks[0].shape[1]), np.uint8)
-        valid = []
-        for i, chunk in enumerate(chunks):
-            block[i], n = pad_slots(chunk, cap)
-            valid.append(n)
+        with self._tel.span("netgen.round.stage"):
+            block = np.zeros((len(chunks), cap, chunks[0].shape[1]),
+                             np.uint8)
+            valid = []
+            for i, chunk in enumerate(chunks):
+                block[i], n = pad_slots(chunk, cap)
+                valid.append(n)
         self._h_occupancy.observe(sum(valid) / (len(chunks) * cap))
         attrs = {"round": round, "valid": sum(valid), **_kernel_attrs(fn)}
         with self._tel.span("netgen.kernel", **attrs):
-            preds = np.asarray(fn(block))            # (M, cap)
+            with self._tel.span("netgen.round.launch"):
+                y = fn(block)
+            with self._tel.span("netgen.round.fetch"):
+                preds = np.asarray(y)                # (M, cap)
         return preds, valid
 
     def _run_slots(self, compiled: Artifact, x: np.ndarray) -> np.ndarray:
@@ -698,10 +703,14 @@ class NetServer:
         attrs = _kernel_attrs(getattr(compiled, "artifact", None))
         outs = []
         for i in range(0, x.shape[0], cap):
-            padded, n = pad_slots(x[i:i + cap], cap)
+            with self._tel.span("netgen.round.stage"):
+                padded, n = pad_slots(x[i:i + cap], cap)
             self._h_occupancy.observe(n / cap)
             with self._tel.span("netgen.kernel", valid=n, **attrs):
-                outs.append(np.asarray(compiled(padded))[:n])
+                with self._tel.span("netgen.round.launch"):
+                    y = compiled(padded)
+                with self._tel.span("netgen.round.fetch"):
+                    outs.append(np.asarray(y)[:n])
         return np.concatenate(outs)
 
     def _stacked_fn(self, names: tuple) -> tuple:

@@ -22,7 +22,8 @@ from _netgen_helpers import images, random_net
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(__file__)), "benchmarks"))
 from check_trace import (  # noqa: E402
-    check_metrics, check_spans, check_trace_dir, parse_prometheus,
+    check_metrics, check_rounds, check_spans, check_trace_dir,
+    parse_prometheus,
 )
 
 SIZES = (12, 9, 4)
@@ -341,6 +342,137 @@ def test_concurrent_predict_many_fallback():
 
 
 # ---------------------------------------------------------------------------
+# Spans on the profiler's clock: the slot round and the engine's cycle
+# ---------------------------------------------------------------------------
+
+ROUND_PHASES = ("netgen.round.stage", "netgen.round.launch",
+                "netgen.round.fetch")
+
+
+def _profiled_events(log_dir, work) -> list:
+    """Run `work()` under `jax.profiler` and return the trace's host
+    events named `netgen.*` as (name, start_ns, end_ns), in start order."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = log_dir.rglob("*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    return sorted(
+        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+        for plane in data.planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events
+        if ev.name.startswith("netgen."))
+
+
+def _names(events, name) -> list:
+    return [ev for ev in events if ev[0] == name]
+
+
+def _serve_rounds(case: str):
+    """(work, rounds): one predict_many of `rounds` slot rounds."""
+    if case == "single":
+        server = _server_with([_net(3)])
+        reqs = {"v0": _x(0, 20)}                 # 8 + 8 + 4 rows
+        rounds = 3
+    else:
+        server = _server_with([_net(3), _net(4)])
+        reqs = {"v0": _x(0, 13), "v1": _x(1, 13)}  # 8 + 5 rows each
+        rounds = 2
+    server.predict_many(reqs)                    # compile outside the trace
+    return (lambda: server.predict_many(reqs)), rounds
+
+
+@pytest.mark.parametrize("case", ["single", "stacked"])
+def test_slot_round_phases_are_profiler_events(tmp_path, case):
+    """Each slot round puts one stage, launch and fetch event in the
+    profiler's trace; launch and fetch lie inside their round's
+    `netgen.kernel` event, stage before it."""
+    work, rounds = _serve_rounds(case)
+    telemetry.enable()
+    events = _profiled_events(tmp_path, work)
+    kernels = _names(events, "netgen.kernel")
+    assert len(kernels) == rounds
+    for name in ROUND_PHASES:
+        assert len(_names(events, name)) == rounds, name
+    stages = _names(events, "netgen.round.stage")
+    launches = _names(events, "netgen.round.launch")
+    fetches = _names(events, "netgen.round.fetch")
+    for stage, kernel, launch, fetch in zip(stages, kernels, launches,
+                                            fetches):
+        assert stage[2] <= kernel[1]
+        assert kernel[1] <= launch[1] <= launch[2] <= fetch[1]
+        assert fetch[2] <= kernel[2]
+    assert len(_names(events, "netgen.dispatch")) == 1
+    spans = [r.as_dict() for r in telemetry.get_registry().spans()]
+    assert check_rounds(spans) == []
+    assert check_spans(spans, require=("netgen.dispatch", "netgen.kernel")
+                       + ROUND_PHASES) == []
+
+
+def test_engine_batch_cycle_is_profiler_events(tmp_path):
+    """Each engine batch puts one `netgen.engine.form`, one
+    `netgen.engine.admit` and one `netgen.engine.resolve` event in the
+    trace, beside its batch."""
+    server = _server_with([_net(3)])
+    server.predict_many({"v0": _x(0, 1)})
+    telemetry.enable()
+    reg = telemetry.get_registry()
+    engine = netgen.ServingEngine(server, max_batch_delay=0.005)
+
+    def work():
+        for seed in range(3):
+            futures = [engine.submit("v0", row) for row in _x(seed, 4)]
+            assert all(f.result(timeout=30) >= 0 for f in futures)
+        # the batcher records a resolve span after setting the futures
+        deadline = time.time() + 10
+        while time.time() < deadline and sum(
+                r.name == "netgen.engine.resolve"
+                for r in reg.spans()) < engine.stats().batches:
+            time.sleep(0.001)
+
+    try:
+        events = _profiled_events(tmp_path, work)
+    finally:
+        engine.shutdown()
+    batches = engine.stats().batches
+    assert batches >= 3
+    assert len(_names(events, "netgen.engine.admit")) == batches
+    assert len(_names(events, "netgen.engine.batch")) == batches
+    assert len(_names(events, "netgen.engine.resolve")) == batches
+    assert len(_names(events, "netgen.engine.form")) == batches
+    spans = [r.as_dict() for r in reg.spans()]
+    assert check_spans(spans, require=("netgen.engine.form",
+                                       "netgen.engine.admit",
+                                       "netgen.engine.batch",
+                                       "netgen.engine.resolve")) == []
+    assert check_rounds(spans) == []
+
+
+def test_tracing_off_puts_no_program_event_in_the_profile(tmp_path):
+    work, _ = _serve_rounds("single")
+    telemetry.disable()
+    assert telemetry.span("netgen.round.stage") is telemetry._NULL_SPAN
+    assert _profiled_events(tmp_path, work) == []
+    assert telemetry.get_registry().spans() == []
+
+
+def test_spans_record_without_jax_profiler(monkeypatch):
+    """Where jax cannot be imported, `enable()` resolves a no-op
+    annotation and spans still record."""
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)
+    telemetry.enable()
+    with telemetry.span("netgen.round.stage"):
+        pass
+    (rec,) = telemetry.get_registry().spans()
+    assert rec.name == "netgen.round.stage" and rec.error is None
+
+
+# ---------------------------------------------------------------------------
 # Session executor lifecycle (satellite 1)
 # ---------------------------------------------------------------------------
 
@@ -517,6 +649,25 @@ def test_check_trace_gate_catches_violations(tmp_path):
         'netgen_slot_occupancy{server="s",quantile="0.5"} 0.0\n'
         'netgen_slot_occupancy_count{server="s"} 0\n')
     assert check_metrics(idle) == []
+    # slot rounds: each kernel span parents one launch and one fetch
+    def rec(sid, name, parent=None):
+        return {"trace_id": 1, "span_id": sid, "parent_id": parent,
+                "name": name, "start_unix": 1.0, "duration_s": 0.1,
+                "attrs": {}, "thread": "t"}
+    round_ok = [rec(1, "netgen.dispatch"), rec(2, "netgen.round.stage", 1),
+                rec(3, "netgen.kernel", 1),
+                rec(4, "netgen.round.launch", 3),
+                rec(5, "netgen.round.fetch", 3)]
+    assert check_rounds(round_ok) == []
+    assert check_spans(round_ok, require=()) == []
+    no_fetch = round_ok[:4]
+    assert any("0 netgen.round.fetch" in e for e in check_rounds(no_fetch))
+    two_launches = round_ok + [rec(6, "netgen.round.launch", 3)]
+    assert any("2 netgen.round.launch" in e
+               for e in check_rounds(two_launches))
+    misparented = round_ok[:4] + [rec(5, "netgen.round.fetch", 1)]
+    assert any("0 netgen.round.fetch" in e
+               for e in check_rounds(misparented))
     # missing files
     errors = check_trace_dir(tmp_path)
     assert any("trace.jsonl missing" in e for e in errors)
