@@ -26,17 +26,19 @@ A third check spans BOTH files (`check_launches`): every
 `netgen.kernel` span dispatched on the fusednet megakernel must record
 exactly ONE Pallas launch (`launches` attr == 1 — the datapath's whole
 point), and `netgen_kernel_launches_total{form="fusednet"}` must cover
-every such dispatch round (warm-up and direct predictor calls may
-launch outside a serving span, so the counter bounds the span count
-from above). Skipped when the trace carries no fusednet traffic.
+every such launch, one or more slot rounds (warm-up and direct
+predictor calls may launch outside a serving span, so the counter
+bounds the span count from above). Skipped when the trace carries no
+fusednet traffic.
 
   PYTHONPATH=src python benchmarks/check_trace.py DIR \\
       [--compile-budget-s 300]
 
-A fourth check (`check_rounds`) gates the slot round's split: every
-`netgen.kernel` span has exactly one `netgen.round.launch` child (the
-predictor call) and one `netgen.round.fetch` child (the blocking copy
-of its result), the spans the benchmark's per-round metrics read.
+A fourth check (`check_rounds`) gates the split of a launch (one or
+more slot rounds): every `netgen.kernel` span has exactly one
+`netgen.round.launch` child (the predictor call) and one
+`netgen.round.fetch` child (the blocking copy of its result), the spans
+the benchmark's per-launch metrics read.
 
 A fifth check (`check_explore`) gates the design-space explorer's
 counting identities when a trace carries explorer traffic: per
@@ -208,12 +210,12 @@ def check_metrics(samples: list[tuple[str, dict, float]]) -> list[str]:
 def check_launches(spans: list[dict],
                    samples: list[tuple[str, dict, float]]) -> list[str]:
     """The megakernel's launch-count contract (empty list == pass): a
-    fusednet dispatch round is ONE Pallas launch. Each `netgen.kernel`
-    span with attrs.form == "fusednet" must carry launches == 1, and
-    the `netgen_kernel_launches_total{form="fusednet"}` counter must be
-    at least the number of such rounds (predictor warm-ups launch
-    outside any serving span, so equality is not required). No-op for
-    traces without fusednet traffic."""
+    fusednet launch (one or more slot rounds) is ONE Pallas launch.
+    Each `netgen.kernel` span with attrs.form == "fusednet" must carry
+    launches == 1, and the `netgen_kernel_launches_total{form="fusednet"}`
+    counter must be at least the number of such launches (predictor
+    warm-ups launch outside any serving span, so equality is not
+    required). No-op for traces without fusednet traffic."""
     errors: list[str] = []
     rounds = [rec for rec in spans
               if rec.get("name") == "netgen.kernel"
@@ -240,9 +242,9 @@ ROUND_CHILDREN = ("netgen.round.launch", "netgen.round.fetch")
 
 
 def check_rounds(spans: list[dict]) -> list[str]:
-    """The slot round's split (empty list == pass): every
-    `netgen.kernel` span parents exactly one `netgen.round.launch` and
-    one `netgen.round.fetch` span."""
+    """The split of a launch, one or more slot rounds (empty list ==
+    pass): every `netgen.kernel` span parents exactly one
+    `netgen.round.launch` and one `netgen.round.fetch` span."""
     children: dict = defaultdict(lambda: dict.fromkeys(ROUND_CHILDREN, 0))
     for rec in spans:
         if rec.get("name") in ROUND_CHILDREN:

@@ -229,10 +229,12 @@ histograms have exact nearest-rank `p50/p95/p99`), `span(name, **attrs)`
 `new_scope(prefix)` (per-instance label), `get_registry()`. The traced
 span tree and metric names are documented in the telemetry module
 docstring; `examples/mnist_fpga_pipeline.py --trace DIR` shows the
-whole thing end to end. The serving path's spans split a slot round
-into `netgen.round.stage` (padding the slot block), then under
-`netgen.kernel` `netgen.round.launch` (the predictor call) and
-`netgen.round.fetch` (the blocking result copy); the engine's batcher
+whole thing end to end. The serving path's spans split a launch (a
+run of 2^k whole slot rounds; one round for a call of at most
+`slot_capacity` rows per version) into `netgen.round.stage` (padding
+the slot block), then under `netgen.kernel` `netgen.round.launch` (the
+predictor call) and `netgen.round.fetch` (the blocking result copy);
+`netgen_slot_rounds_total` counts the rounds served; the engine's batcher
 adds `netgen.engine.form` (waiting for and forming a batch),
 `netgen.engine.admit` (deadlines, grouping, stacking its rows) and
 `netgen.engine.resolve` (setting its futures) beside

@@ -29,9 +29,14 @@ ROADMAP's "Serving specialized programs" item:
       through the registry) — M versions, one XLA call. For the
       bit-plane datapath (`pallas[planes=true]` / `fusednet=true`) the
       stacked dispatch is the whole-net megakernel: one persistent
-      Pallas launch per dispatch round for all M versions and every
+      Pallas launch per dispatch for all M versions and every
       layer, recorded on the `netgen.kernel` span (form/launches) and
-      in `netgen_kernel_launches_total{form}`. When a device
+      in `netgen_kernel_launches_total{form}`. A call of more rows
+      than one slot round holds is served in few launches, each a run
+      of whole slot rounds (a power of two of them, at most
+      `MAX_ROUNDS_PER_LAUNCH`), fetched once each; a call of at most
+      `slot_capacity` rows per version is one round, one launch.
+      When a device
       mesh with a data axis is active (`repro.parallel.sharding
       .use_mesh`), the stacked dispatch additionally shards its slot
       (batch) dimension across the mesh with `shard_map` — the
@@ -74,9 +79,20 @@ from repro.serve.slots import pad_slots
 
 __all__ = [
     "CacheCounters", "CacheKey", "CacheStats", "CompileCache",
-    "DEFAULT_CACHE", "NetServer", "cached_compile_net",
-    "stack_layered_weights",
+    "DEFAULT_CACHE", "MAX_ROUNDS_PER_LAUNCH", "NetServer",
+    "cached_compile_net", "stack_layered_weights",
 ]
+
+# The most slot rounds one launch covers. Each launch pays a fixed host
+# cost, the argument transfer and the blocking fetch of its result
+# (about 1.4 ms a launch on a TPU v5e, against a kernel of 75-95 us a
+# 256-row round of the benchmark's nets), so a multi-round call is
+# served in launches of 2^k whole rounds. 64 pays that cost once per up
+# to 64 rounds, at most 1/64 of what a launch per round paid, and
+# bounds what the mechanism costs: at most 7 compiled programs per
+# version set (1, 2, 4 ... 64 rounds) and one launch's input at
+# 64 * slot_capacity * n_in bytes per version (12.8 MB at 256 x 784).
+MAX_ROUNDS_PER_LAUNCH = 64
 
 
 # ---------------------------------------------------------------------------
@@ -367,9 +383,9 @@ def stack_layered_weights(circuits: Sequence[Circuit]
 def _kernel_attrs(fn) -> dict:
     """The datapath attributes a `netgen.kernel` span carries when the
     predictor declares them (pallas builds do): `form` names the
-    executed datapath and `launches` the pallas_call count one dispatch
-    performs — `benchmarks/check_trace.py` gates that every fusednet
-    round records exactly one launch."""
+    executed datapath and `launches` the pallas_call count one predictor
+    call performs — `benchmarks/check_trace.py` gates that every fusednet
+    launch (one or more slot rounds) records exactly one pallas_call."""
     dp = getattr(fn, "datapath", None)
     if dp is None:
         return {}
@@ -380,12 +396,26 @@ def _kernel_attrs(fn) -> dict:
     return attrs
 
 
+def _launch_rounds(rounds: int) -> list[int]:
+    """The launches that serve `rounds` slot rounds: powers of two of
+    at most MAX_ROUNDS_PER_LAUNCH rounds, largest first (32 -> [32],
+    33 -> [32, 1], 100 -> [64, 32, 4])."""
+    out = []
+    while rounds > 0:
+        k = min(MAX_ROUNDS_PER_LAUNCH, 1 << (rounds.bit_length() - 1))
+        out.append(k)
+        rounds -= k
+    return out
+
+
 def _shard_stacked(fn, mesh, capacity: int):
-    """Wrap a stacked dispatch ((M, cap, n_in) -> (M, cap)) in
+    """Wrap a stacked dispatch ((M, B, n_in) -> (M, B)) in
     `shard_map` over the mesh's data axes, splitting the slot (batch)
-    dimension — each device serves cap / n_data rows of every version.
-    Returns None (single-device fallback) when the mesh has no data
-    axis or the capacity does not divide across it."""
+    dimension — each device serves B / n_data rows of every version.
+    A launch's B is a whole number of slot rounds (k * capacity), so it
+    divides across the mesh whenever the capacity does. Returns None
+    (single-device fallback) when the mesh has no data axis or the
+    capacity does not divide across it."""
     import jax
 
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
@@ -430,7 +460,8 @@ class NetServer:
     Single-version requests (`predict`) route to that version's cached
     `Artifact` with fixed-capacity slot batching (the
     `repro.serve.engine` pattern — one live jit trace per model; larger
-    batches are chunked). Multi-version requests (`predict_many`) stack
+    batches are served in launches of 2^k whole slot rounds, see
+    `MAX_ROUNDS_PER_LAUNCH`). Multi-version requests (`predict_many`) stack
     compatible versions' ExecutionPlans into one jitted multi-net
     dispatch — sharded over the slot dimension with `shard_map` when a
     mesh with a data axis is active (see the module doc); incompatible
@@ -497,6 +528,8 @@ class NetServer:
             for path in ("single", "stacked", "sharded", "fallback")}
         self._h_occupancy = self._tel.histogram(
             "netgen_slot_occupancy", server=self._scope)
+        self._slot_rounds = self._tel.counter(
+            "netgen_slot_rounds_total", server=self._scope)
 
     @property
     def dispatch_counts(self) -> dict:
@@ -595,15 +628,17 @@ class NetServer:
         when the requested versions are stack-compatible (else per-version
         fallback). Returns {version: predictions}.
 
-        Skewed batches do not waste rounds: each slot round dispatches
-        only the versions that still have requested rows (an exhausted
-        version's padded all-zero block would burn kernel work and skew
-        the occupancy histogram with rows nobody asked for), and the
-        last remaining version finishes through the single-version slot
+        Skewed batches do not waste rounds: the call is cut into
+        segments by which versions still have requested rows, each
+        segment's rounds are stacked into launches of 2^k whole rounds
+        (`_launch_rounds`), an exhausted version's padded all-zero block
+        is never launched (it would burn kernel work and skew the
+        occupancy histogram with rows nobody asked for), and the last
+        remaining version finishes through the single-version slot
         path. `netgen_predict_latency_seconds` records per-version
-        SERVICE time — the rounds a version actually participated in —
-        so a 1-row version no longer inherits the whole-call latency of
-        a 4096-row co-batched one."""
+        SERVICE time — the launches a version actually participated in
+        — so a 1-row version no longer inherits the whole-call latency
+        of a 4096-row co-batched one."""
         t0 = time.perf_counter()
         names = tuple(sorted(requests))
         compiled = {v: self.compiled_for(v) for v in names}
@@ -637,14 +672,16 @@ class NetServer:
         if sharded:
             self._dispatch["sharded"].inc()
         cap = self.slot_capacity
-        rounds = max((x.shape[0] + cap - 1) // cap for x in xs.values())
+        ends = {v: (x.shape[0] + cap - 1) // cap for v, x in xs.items()}
+        rounds = max(ends.values())
         out: dict[str, list] = {v: [] for v in names}
         service = {v: 0.0 for v in names}
         with self._tel.span("netgen.dispatch",
                             path="sharded" if sharded else "stacked",
                             versions=len(names), rounds=rounds):
-            for r in range(rounds):
-                active = tuple(v for v in names if xs[v].shape[0] > r * cap)
+            r = 0
+            while r < rounds:
+                active = tuple(v for v in names if ends[v] > r)
                 if len(active) == 1:
                     (v,) = active
                     t1 = time.perf_counter()
@@ -655,13 +692,17 @@ class NetServer:
                 # a strict subset of a stackable set is itself stackable;
                 # its multi-net fn is cached in _multi like the full set's
                 afn = fn if active == names else self._stacked_fn(active)[0]
-                chunks = [xs[v][r * cap:(r + 1) * cap] for v in active]
-                t1 = time.perf_counter()
-                preds, valid = self._stacked_round(afn, chunks, round=r)
-                dt = time.perf_counter() - t1
-                for i, v in enumerate(active):
-                    out[v].append(preds[i, :valid[i]])
-                    service[v] += dt
+                # every active version has rows in each round up to the
+                # first one's end: those rounds stack whole into launches
+                for k in _launch_rounds(min(ends[v] for v in active) - r):
+                    chunks = [xs[v][r * cap:(r + k) * cap] for v in active]
+                    t1 = time.perf_counter()
+                    preds, valid = self._stacked_round(afn, chunks, round=r)
+                    dt = time.perf_counter() - t1
+                    for i, v in enumerate(active):
+                        out[v].append(preds[i, :valid[i]])
+                        service[v] += dt
+                    r += k
         for v in names:
             self._requests(v).inc()
             self._latency(v).observe(service[v])
@@ -670,47 +711,72 @@ class NetServer:
 
     # -- internals -----------------------------------------------------------
 
+    def _observe_rounds(self, valid: list, rounds: int) -> None:
+        """One `netgen_slot_occupancy` observation per slot round of a
+        launch, over the rows requested in that round (`valid` holds
+        each version's requested rows of the launch), and the launch's
+        rounds into `netgen_slot_rounds_total`."""
+        cap = self.slot_capacity
+        for j in range(rounds):
+            used = sum(min(max(n - j * cap, 0), cap) for n in valid)
+            self._h_occupancy.observe(used / (len(valid) * cap))
+        self._slot_rounds.inc(rounds)
+
     def _stacked_round(self, fn, chunks: list, round: int = 0
                        ) -> tuple[np.ndarray, list]:
-        """ONE stacked dispatch round — the slot mechanics shared by
+        """ONE stacked launch — the slot mechanics shared by
         `predict_many` and the async serving engine
         (`repro.netgen.engine`): pad each version's chunk into the
-        (M, cap, n_in) slot block, observe occupancy over the slots
-        actually requested, run the jitted multi-net fn. Returns the
-        (M, cap) predictions and the per-version valid row counts."""
+        (M, rounds * cap, n_in) slot block, observe occupancy over the
+        slots actually requested, run the jitted multi-net fn. The
+        launch covers ceil(longest chunk / cap) slot rounds: one for the
+        engine, which hands over at most cap rows a version, and 2^k for
+        `predict_many`'s multi-round calls. `round` is the index of the
+        launch's first slot round within its call. Returns the
+        (M, rounds * cap) predictions and the per-version valid row
+        counts."""
         cap = self.slot_capacity
+        rounds = -(-max(c.shape[0] for c in chunks) // cap)
         with self._tel.span("netgen.round.stage"):
-            block = np.zeros((len(chunks), cap, chunks[0].shape[1]),
-                             np.uint8)
+            block = np.zeros(
+                (len(chunks), rounds * cap, chunks[0].shape[1]), np.uint8)
             valid = []
             for i, chunk in enumerate(chunks):
-                block[i], n = pad_slots(chunk, cap)
+                block[i], n = pad_slots(chunk, rounds * cap)
                 valid.append(n)
-        self._h_occupancy.observe(sum(valid) / (len(chunks) * cap))
-        attrs = {"round": round, "valid": sum(valid), **_kernel_attrs(fn)}
+        self._observe_rounds(valid, rounds)
+        attrs = {"round": round, "valid": sum(valid), "rounds": rounds,
+                 **_kernel_attrs(fn)}
         with self._tel.span("netgen.kernel", **attrs):
             with self._tel.span("netgen.round.launch"):
                 y = fn(block)
             with self._tel.span("netgen.round.fetch"):
-                preds = np.asarray(y)                # (M, cap)
+                preds = np.asarray(y)        # (M, rounds * cap)
         return preds, valid
 
     def _run_slots(self, compiled: Artifact, x: np.ndarray) -> np.ndarray:
+        """Serve one version's rows in launches of 2^k whole slot rounds
+        (`_launch_rounds`): a full launch passes its slice of `x` as it
+        is, only the last one is padded, and each is fetched once."""
         _validate_batch(x, compiled.circuit.n_inputs)
         cap = self.slot_capacity
         if x.shape[0] == 0:
             return np.zeros((0,), np.int64)
         attrs = _kernel_attrs(getattr(compiled, "artifact", None))
         outs = []
-        for i in range(0, x.shape[0], cap):
+        start = 0
+        for rounds in _launch_rounds(-(-x.shape[0] // cap)):
+            rows = rounds * cap
             with self._tel.span("netgen.round.stage"):
-                padded, n = pad_slots(x[i:i + cap], cap)
-            self._h_occupancy.observe(n / cap)
-            with self._tel.span("netgen.kernel", valid=n, **attrs):
+                padded, n = pad_slots(x[start:start + rows], rows)
+            self._observe_rounds([n], rounds)
+            with self._tel.span("netgen.kernel", valid=n, rounds=rounds,
+                                **attrs):
                 with self._tel.span("netgen.round.launch"):
                     y = compiled(padded)
                 with self._tel.span("netgen.round.fetch"):
                     outs.append(np.asarray(y)[:n])
+            start += rows
         return np.concatenate(outs)
 
     def _stacked_fn(self, names: tuple) -> tuple:

@@ -74,9 +74,11 @@ Instrumented span tree (what a trace of one request lifecycle nests):
                             opened on the batcher thread, so it roots
                             its own trace and parents the dispatch
       netgen.dispatch       path=single|stacked|sharded|fallback
-        netgen.round.stage  one slot round's host staging: pad into
-                            the (cap, n_in) / (M, cap, n_in) block
-        netgen.kernel       one per jitted call (slot round)
+        netgen.round.stage  one launch's host staging: pad into the
+                            (k*cap, n_in) / (M, k*cap, n_in) block of
+                            k = 2^j whole slot rounds
+        netgen.kernel       one per jitted call (launch); attrs
+                            `rounds` (k), `valid`, `form`, `launches`
           netgen.round.launch   the predictor call until it returns
                                 (argument transfer and enqueue)
           netgen.round.fetch    np.asarray of its result (the wait
@@ -91,13 +93,16 @@ Instrumented span tree (what a trace of one request lifecycle nests):
 Serving metrics: `netgen_predict_latency_seconds{server,version}`
 records per-version SERVICE time and `netgen_requests_total` counts one
 increment per dispatch call per version — `benchmarks/check_trace.py`
-gates latency count == request count.
+gates latency count == request count. `netgen_slot_occupancy{server}`
+takes one observation per slot round and `netgen_slot_rounds_total
+{server}` adds the slot rounds each launch covers, so its change over
+that of `netgen_kernel_launches_total` is the rounds per launch.
 `netgen_kernel_launches_total{form}` counts Pallas kernel launches per
 datapath form (`kernel_launches(form)` is the accessor backends use):
 the per-layer chains record depth launches per call (times M for the
 lax.map multi dispatch) while the fusednet megakernel records exactly
 ONE per call — `benchmarks/check_trace.py` gates that every fusednet
-`netgen.kernel` dispatch-round span carries launches == 1. The online engine
+`netgen.kernel` launch span carries launches == 1. The online engine
 (`repro.netgen.engine`) adds, per `engine=` scope:
 `netgen_engine_submitted/completed/batches_total`,
 `netgen_engine_rejected_total{reason=queue_full|deadline|closed}`, the

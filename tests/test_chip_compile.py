@@ -1,5 +1,6 @@
 """Compile rehearsals for the TPU: every Pallas datapath of the serving
-path, at the paper's 784-500-10 width and batch 256, compiled by the TPU
+path, at the paper's 784-500-10 width and batch 256 (the megakernel also
+at NetServer's largest multi-round launch), compiled by the TPU
 compiler for a described (not attached) v5e chip.
 
 Interpret mode accepts block shapes, reductions and dots that Mosaic
@@ -16,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.binary_matvec import binary_matvec as bmv
 from repro.kernels.fused_mlp import fused_mlp as fm
+from repro.netgen.serve import MAX_ROUNDS_PER_LAUNCH
 
 N_IN, N_HIDDEN, N_OUT, PLANES = 784, 500, 10, 3
 IN_WORDS = -(-N_IN // 32)               # 25 packed input words
@@ -69,6 +71,9 @@ def _megakernel_planes(sharding, lead=()):
     (None, BATCH),       # single net
     (2, BATCH),          # stacked M=2 (NetServer's stacked dispatch)
     (2, BATCH // 4),     # stacked, one shard of the four-chip data mesh
+    # NetServer's largest launch: MAX_ROUNDS_PER_LAUNCH slot rounds
+    (None, MAX_ROUNDS_PER_LAUNCH * BATCH),
+    (2, MAX_ROUNDS_PER_LAUNCH * BATCH),
 ])
 def test_fusednet_compiles_for_v5e(one_chip, models, batch):
     lead = () if models is None else (models,)

@@ -1,10 +1,11 @@
 """Compile-cache serving tests: content-addressed hits/misses, LRU
 eviction, thread safety, input validation, the NetServer's stacked
 multi-net dispatch (ISSUE 2 acceptance: 4 versions in one jitted call,
-bit-exact vs serving each CompiledNet individually), and the
+bit-exact vs serving each CompiledNet individually), the
 mesh-sharded stacked dispatch (ISSUE 4: shard_map over the slot
 dimension when a mesh with a data axis is active, single-device
-fallback otherwise)."""
+fallback otherwise), and multi-round calls served in launches of 2^k
+whole slot rounds."""
 import os
 import subprocess
 import sys
@@ -16,10 +17,15 @@ import jax.numpy as jnp
 
 from repro.core import quantize
 from repro import netgen
+from repro.netgen import telemetry
 from repro.netgen.serve import _pass_fingerprint
 from repro.serve.engine import pad_slots
 
 from _netgen_helpers import images, random_net
+
+sys.path.insert(0, os.path.join(
+    os.path.dirname(os.path.dirname(__file__)), "benchmarks"))
+from check_trace import check_launches, check_rounds, check_spans  # noqa: E402
 
 
 def _random_net(seed: int, sizes=(12, 9, 4), lo=-5, hi=5):
@@ -461,9 +467,12 @@ for name, net in nets.items():
 
 single = server.predict_many(reqs)                   # single-device path
 assert server.dispatch_counts["sharded"] == 0
+rounds = server._slot_rounds.value
 with shd.use_mesh(make_host_mesh(data=8)):           # 8-way batch sharding
+    # 19 rows a version: one launch of 2 slot rounds, (3, 32, 12) over 8
     sharded = server.predict_many(reqs)
 assert server.dispatch_counts["sharded"] == 1, server.dispatch_counts
+assert server._slot_rounds.value - rounds == 2
 for name, net in nets.items():
     ref = np.asarray(quantize.predict_quantized(net)(jnp.asarray(reqs[name])))
     assert np.array_equal(sharded[name], single[name]), name
@@ -650,3 +659,162 @@ def test_predict_many_records_per_version_service_time():
                         server=server._scope, version="big")
     # small saw round 0 only; big additionally paid 15 more rounds
     assert small.sum < big.sum
+
+
+# ---------------------------------------------------------------------------
+# Multi-round calls: launches of 2^k whole slot rounds, fetched once each
+# ---------------------------------------------------------------------------
+
+CAP = 4
+# rows of a call -> the slot rounds of each launch that serves it; a call
+# of at most CAP rows is the one (CAP, n_in) / (M, CAP, n_in) launch
+LAUNCHES = {
+    1: [1], CAP: [1], CAP + 1: [2], 3 * CAP + 5: [4, 1],
+    32 * CAP: [32], 33 * CAP: [32, 1], 100 * CAP + 1: [64, 32, 4, 1],
+}
+# rows per version -> (versions, slot rounds) of each launch: stacked
+# while every version of the launch has rows, then the remaining subset
+SKEWED = [
+    ((1, 4 * CAP), [(2, 1), (1, 2), (1, 1)]),
+    ((33 * CAP, 3 * CAP + 5), [(2, 4), (2, 1), (1, 16), (1, 8), (1, 4)]),
+    ((100 * CAP + 1, 32 * CAP), [(2, 32), (1, 64), (1, 4), (1, 1)]),
+    ((CAP + 1, 33 * CAP, 3 * CAP + 5),
+     [(3, 2), (2, 2), (2, 1), (1, 16), (1, 8), (1, 4)]),
+]
+
+
+@pytest.fixture
+def traced():
+    """Span tracing on for one test, over zeroed metrics."""
+    telemetry.reset()
+    telemetry.enable()
+    yield telemetry.get_registry()
+    telemetry.disable()
+    telemetry.reset()
+
+
+def _launch_spy(monkeypatch) -> list:
+    """The block shape of every predictor call, in order: single-version
+    artifacts and stacked multi-net dispatches alike."""
+    from repro.netgen import serve as serve_mod
+    from repro.netgen.session import Artifact
+
+    shapes = []
+    call, build = Artifact.__call__, serve_mod.compile_multi
+
+    def single(self, x):
+        shapes.append(x.shape)
+        return call(self, x)
+
+    def multi(*args, **kwargs):
+        fn = build(*args, **kwargs)
+
+        def stacked(block):
+            shapes.append(block.shape)
+            return fn(block)
+        return stacked
+
+    monkeypatch.setattr(Artifact, "__call__", single)
+    monkeypatch.setattr(serve_mod, "compile_multi", multi)
+    return shapes
+
+
+def _assert_launch_records(server, reg, rounds: list) -> list:
+    """One `netgen.kernel` span per launch with its slot rounds in
+    `rounds`, the rounds counted in `netgen_slot_rounds_total`, one
+    occupancy observation per slot round, one latency observation per
+    version, and the launch split that `check_trace` gates. Returns the
+    finished spans as dicts."""
+    spans = [r.as_dict() for r in reg.spans()]
+    kernels = [r for r in spans if r["name"] == "netgen.kernel"]
+    assert [r["attrs"]["rounds"] for r in kernels] == rounds
+    assert reg.counter("netgen_slot_rounds_total",
+                       server=server._scope).value == sum(rounds)
+    assert reg.histogram("netgen_slot_occupancy",
+                         server=server._scope).count == sum(rounds)
+    for v in server.versions():
+        assert reg.histogram("netgen_predict_latency_seconds",
+                             server=server._scope, version=v).count == 1
+    assert check_rounds(spans) == []
+    assert check_spans(spans, require=("netgen.dispatch", "netgen.kernel",
+                                       "netgen.round.stage")) == []
+    return spans
+
+
+@pytest.mark.parametrize("versions", [1, 2])
+@pytest.mark.parametrize("n", sorted(LAUNCHES))
+def test_multi_round_call_served_in_power_of_two_launches(
+        monkeypatch, traced, n, versions):
+    """A call of n rows per version is bit-exact and reaches the
+    predictor as launches of 2^k whole slot rounds, largest first; a
+    call of at most one slot round is the one launch it always was."""
+    shapes = _launch_spy(monkeypatch)
+    server = netgen.NetServer(slot_capacity=CAP, warmup=False)
+    nets = {f"v{i}": _random_net(140 + i) for i in range(versions)}
+    for name, net in nets.items():
+        server.register(name, net)
+    reqs = {name: _images(140 + n + i, n, 12)
+            for i, name in enumerate(nets)}
+    out = server.predict_many(reqs)
+    for name, net in nets.items():
+        np.testing.assert_array_equal(out[name], _ref(net, reqs[name]),
+                                      err_msg=name)
+    lead = () if versions == 1 else (versions,)
+    assert shapes == [lead + (k * CAP, 12) for k in LAUNCHES[n]]
+    _assert_launch_records(server, traced, LAUNCHES[n])
+    path = "single" if versions == 1 else "stacked"
+    assert server.dispatch_counts[path] == 1
+
+
+@pytest.mark.parametrize("rows, launches", SKEWED,
+                         ids=["x".join(map(str, r)) for r, _ in SKEWED])
+def test_skewed_multi_round_call_launches_only_versions_with_rows(
+        monkeypatch, traced, rows, launches):
+    """Skewed calls stack whole rounds while every version of the launch
+    has rows, then serve the rest through the remaining subset or the
+    single-version tail; an exhausted version never rides a launch."""
+    shapes = _launch_spy(monkeypatch)
+    server = netgen.NetServer(slot_capacity=CAP, warmup=False)
+    nets = {f"v{i}": _random_net(150 + i) for i in range(len(rows))}
+    for name, net in nets.items():
+        server.register(name, net)
+    reqs = {name: _images(150 + i, b, 12)
+            for i, (name, b) in enumerate(zip(nets, rows))}
+    out = server.predict_many(reqs)
+    for name, net in nets.items():
+        np.testing.assert_array_equal(out[name], _ref(net, reqs[name]),
+                                      err_msg=name)
+    assert shapes == [((m,) if m > 1 else ()) + (k * CAP, 12)
+                      for m, k in launches]
+    _assert_launch_records(server, traced, [k for _, k in launches])
+    assert server.dispatch_counts["stacked"] == 1
+
+
+@pytest.mark.parametrize("versions", [1, 2])
+def test_fusednet_multi_round_launch_passes_the_launch_gates(
+        traced, versions):
+    """On the megakernel a multi-round launch is still ONE pallas_call:
+    `check_launches` holds, and the slot rounds over the kernel
+    launches read the rounds per launch."""
+    cap = 8
+    server = netgen.NetServer(target="pallas[fusednet=true]",
+                              slot_capacity=cap, warmup=False)
+    nets = {f"v{i}": random_net(160 + i, (20, 13, 5), lo=-5, hi=5)
+            for i in range(versions)}
+    for name, net in nets.items():
+        server.register(name, net)
+    reqs = {name: images(160 + i, 3 * cap + 5, 20)   # 4 rounds: 1 launch
+            for i, name in enumerate(nets)}
+    launched = telemetry.kernel_launches("fusednet").value
+    out = server.predict_many(reqs)
+    for name, net in nets.items():
+        np.testing.assert_array_equal(out[name], _ref(net, reqs[name]),
+                                      err_msg=name)
+    spans = _assert_launch_records(server, traced, [4])
+    assert [r["attrs"]["form"] for r in spans
+            if r["name"] == "netgen.kernel"] == ["fusednet"]
+    launches = telemetry.kernel_launches("fusednet").value - launched
+    assert launches == 1
+    samples = [("netgen_kernel_launches_total", {"form": "fusednet"},
+                float(telemetry.kernel_launches("fusednet").value))]
+    assert check_launches(spans, samples) == []

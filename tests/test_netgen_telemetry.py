@@ -374,31 +374,32 @@ def _names(events, name) -> list:
 
 
 def _serve_rounds(case: str):
-    """(work, rounds): one predict_many of `rounds` slot rounds."""
+    """(work, launches): one predict_many served in `launches` launches
+    of 2^k whole slot rounds."""
     if case == "single":
         server = _server_with([_net(3)])
-        reqs = {"v0": _x(0, 20)}                 # 8 + 8 + 4 rows
-        rounds = 3
+        reqs = {"v0": _x(0, 20)}          # rounds 8 + 8 + 4: launches 2 + 1
+        launches = 2
     else:
         server = _server_with([_net(3), _net(4)])
-        reqs = {"v0": _x(0, 13), "v1": _x(1, 13)}  # 8 + 5 rows each
-        rounds = 2
+        reqs = {"v0": _x(0, 13), "v1": _x(1, 13)}  # 8 + 5 each: one launch
+        launches = 1
     server.predict_many(reqs)                    # compile outside the trace
-    return (lambda: server.predict_many(reqs)), rounds
+    return (lambda: server.predict_many(reqs)), launches
 
 
 @pytest.mark.parametrize("case", ["single", "stacked"])
 def test_slot_round_phases_are_profiler_events(tmp_path, case):
-    """Each slot round puts one stage, launch and fetch event in the
-    profiler's trace; launch and fetch lie inside their round's
-    `netgen.kernel` event, stage before it."""
-    work, rounds = _serve_rounds(case)
+    """Each launch (one or more slot rounds) puts one stage, launch and
+    fetch event in the profiler's trace; launch and fetch lie inside
+    their launch's `netgen.kernel` event, stage before it."""
+    work, launches = _serve_rounds(case)
     telemetry.enable()
     events = _profiled_events(tmp_path, work)
     kernels = _names(events, "netgen.kernel")
-    assert len(kernels) == rounds
+    assert len(kernels) == launches
     for name in ROUND_PHASES:
-        assert len(_names(events, name)) == rounds, name
+        assert len(_names(events, name)) == launches, name
     stages = _names(events, "netgen.round.stage")
     launches = _names(events, "netgen.round.launch")
     fetches = _names(events, "netgen.round.fetch")
