@@ -1,10 +1,12 @@
 """The control of the comparison that decides `correct`: the plain reference
 put in the program's place, one precision below what the configuration
 states. Every configuration states uint8 pixels; the control holds them in
-4 bits (`x >> 4` against `threshold >> 4`), the step that would halve the
-bytes each request sends. At each request of a run's window (the same
-weights and the same images as that run of the seed) it reads the widest gap
-by which the class the control puts first lies below the reference's best.
+4 bits, the step that would halve the bytes each request sends: the
+configuration's reference computes with `input_shift=4` (for a dense chain,
+`x >> 4` against `threshold >> 4`). At each request of a run's window (the
+same weights and the same images as that run of the seed) it reads the
+widest gap by which the class the control puts first lies below the
+reference's best.
 A sound comparison must find the control not correct.
 
     python3 bench/control.py --workload <cell> --seeds 1,2,3 --seconds <s>
@@ -33,9 +35,9 @@ def control_gap(root: Path, workload: str, seed: int, seconds: float) -> dict:
     config, traffic = spec["config"], spec["traffic"]
     versions = run.make_versions(root, config, int(traffic["versions"]))
     names = [v for v, _ in versions]
-    inputs = generator.make_inputs(traffic, config["widths"][0], names, seconds, seed)
+    inputs = generator.make_inputs(traffic, spec["net"].row_length(config), names, seconds,
+                                   seed)
     ref_mod = run.load_file(root / "bench" / f"{config['reference']}.py", "bench_reference")
-    thr = int(config["input_threshold"])
     if traffic["mode"] == "online":
         xs = [(ws, inputs["pool"][np.unique(inputs["idx"][inputs["ver"] == k])])
               for k, (_, ws) in enumerate(versions)]
@@ -43,8 +45,8 @@ def control_gap(root: Path, workload: str, seed: int, seconds: float) -> dict:
         xs = [(ws, blk[v]) for blk in inputs["blocks"] for v, ws in versions]
     gap, n = 0.0, 0
     for ws, x in xs:
-        ref = ref_mod.logits(ws, thr, x)
-        low = ref_mod.logits(ws, thr, x, input_shift=INPUT_SHIFT)
+        ref = ref_mod.logits(ws, config, x)
+        low = ref_mod.logits(ws, config, x, input_shift=INPUT_SHIFT)
         gap = max(gap, ref_mod.widest_gap(ref, low.argmax(axis=1)))
         n += x.shape[0]
     return {"workload": workload, "seed": seed, "rows": n, "max_gap": gap,

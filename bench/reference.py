@@ -23,15 +23,17 @@ def _check_exact(weights) -> None:
             raise ValueError(f"a column sums to {worst} >= 2**24: float32 would round")
 
 
-def logits(weights, input_threshold: int, x_uint8: np.ndarray,
+def logits(weights, config: dict, x_uint8: np.ndarray,
            input_shift: int = 0) -> np.ndarray:
-    """Integer logits (rows, n_classes) as float32, computed in row blocks.
+    """Integer logits (rows, n_classes) as float32, computed in row blocks,
+    of the net whose weights are given and whose `input_threshold` the
+    configuration states.
 
     `input_shift` > 0 holds the pixels in 8 - shift bits first (the control):
     the comparator then sees `x >> shift` against `threshold >> shift`."""
     _check_exact(weights)
     ws = [np.asarray(w, np.float32) for w in weights]
-    thr = int(input_threshold) >> input_shift
+    thr = int(config["input_threshold"]) >> input_shift
     out = []
     for i in range(0, x_uint8.shape[0], ROW_BLOCK):
         x = x_uint8[i:i + ROW_BLOCK]

@@ -3,12 +3,14 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything is found by name from BENCHMARK.json: the cell's configuration
-(`configs[].file`, whose versions name a weight scheme in
-`bench/weights/<scheme>.py` and whose `reference` names the plain reference
-`bench/<reference>.py`), its traffic mix (`bench/traffic/<traffic>.json`,
-read by `bench/generator.py`) and each per-layer metric's reader
-(`bench/metrics/<metric>.py`, or `<stem>.py` for a metric `<stem>.<split>`:
-a `read(run)` that returns a number or None).
+(`configs[].file`, whose `net` names the module of its shape of net,
+`bench/nets/<net>.py`, `dense_chain` where it names none; whose versions name
+a weight scheme in `bench/weights/<scheme>.py`; and whose `reference` names
+the plain reference `bench/<reference>.py`), its traffic mix
+(`bench/traffic/<traffic>.json`, read by `bench/generator.py`) and each
+per-layer metric's reader (`bench/metrics/<metric>.py`, or `<stem>.py` for a
+metric `<stem>.<split>`: a `read(run)` that returns a number or None). Weight
+schemes and references are given the configuration as it is run.
 
 A run: checks for the chips the cell asks for (none: exit 2, no result);
 makes the configuration's weights and the seed's inputs; registers the
@@ -37,6 +39,7 @@ import importlib.util  # noqa: E402
 import itertools  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 from concurrent.futures import wait  # noqa: E402
@@ -60,6 +63,7 @@ STORE_DIR = ".bench/netgen_store"  # netgen's ArtifactStore: the nets' built IR
 TRACE_DIR = ".bench/trace"         # profiler output, read and removed
 LIMITS = {"max_gap": 0.0, "unanswered": 0.0, "errored": 0.0}
 STREAM_WEIGHTS = 1
+DEFAULT_NET = "dense_chain"   # the net of a configuration that names none
 OVER_LIMIT = 1e9   # printed in place of an infinite gap (a class out of range)
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                   "/jax/core/compile/backend_compile_duration")
@@ -83,9 +87,17 @@ def _listed(entry: dict, cell: str) -> bool:
     return "workloads" not in entry or cell in entry["workloads"]
 
 
+def load_net(root: Path, config: dict):
+    """The module of the configuration's net: `bench/nets/<net>.py`."""
+    name = config.get("net", DEFAULT_NET)
+    if not isinstance(name, str) or not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_-]*", name):
+        raise LayoutError(f"net {name!r} of {config.get('name')!r} is not a module name")
+    return load_file(root / "bench" / "nets" / f"{name}.py", "bench_net_" + name)
+
+
 def load_cell(root: Path, name: str) -> dict:
-    """The cell `name` of `root`/BENCHMARK.json with its configuration,
-    traffic mix, and the metrics it reports."""
+    """The cell `name` of `root`/BENCHMARK.json with its configuration, the
+    module of its net, its traffic mix, and the metrics it reports."""
     layout = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in layout["workloads"]}
     if name not in cells:
@@ -98,7 +110,7 @@ def load_cell(root: Path, name: str) -> dict:
         raise LayoutError(f"missing {traffic_file}")
     per_layer = [(m["name"], m["unit"], load_metric(root, m["name"]))
                  for m in layout["per_layer"] if _listed(m, name)]
-    return {"cell": cell, "config": config,
+    return {"cell": cell, "config": config, "net": load_net(root, config),
             "traffic": json.loads(traffic_file.read_text()),
             "end_to_end": [(m["name"], m["unit"]) for m in layout["end_to_end"]
                            if _listed(m, name)],
@@ -116,7 +128,8 @@ def load_metric(root: Path, name: str):
 
 
 def make_versions(root: Path, config: dict, n: int) -> list:
-    """[(name, [int32 weight matrices])] of the config's first `n` versions.
+    """[(name, weights)] of the config's first `n` versions, each as its
+    scheme makes it from the configuration (for a dense chain, int32 matrices).
 
     The weights are the configuration's, drawn from its `weight_seed`, not
     from `--seed`: the program compiles weights into its XLA program as
@@ -131,26 +144,26 @@ def make_versions(root: Path, config: dict, n: int) -> list:
         scheme = v["weights"]["scheme"]
         mod = load_file(root / "bench" / "weights" / f"{scheme}.py", "bench_weights_" + scheme)
         out.append((v["name"], mod.make(generator.rng(config["weight_seed"], STREAM_WEIGHTS),
-                                        config["widths"], v["weights"])))
+                                        config, v["weights"])))
     return out
 
 
-def serve(root: Path, config: dict, traffic: dict, versions: list, inputs: dict):
+def serve(root: Path, config: dict, net, traffic: dict, versions: list, inputs: dict):
     """The served path, set up and warmed: a `NetServer` over the configured
-    target with the versions registered, and for an online mix a
-    `ServingEngine` in front (else None). Warm-up runs every program the
-    window will run and no other: offline, the window's own first call;
-    online, each set of versions a round can hold, through the server, then
-    a few requests through the engine. Warm-up answers are not judged."""
+    target with the versions registered, each as the net module builds it,
+    and for an online mix a `ServingEngine` in front (else None). Warm-up
+    runs every program the window will run and no other: offline, the
+    window's own first call; online, each set of versions a round can hold,
+    through the server, then a few requests through the engine. Warm-up
+    answers are not judged."""
     from repro import netgen
-    from repro.core.quantize import QuantizedNet
 
     names = [v for v, _ in versions]
     server = netgen.NetServer(session=netgen.Session(store=str(root / STORE_DIR)),
                               target=config["target"],
                               slot_capacity=int(config["slot_capacity"]))
     for v, ws in versions:
-        server.register(v, QuantizedNet(weights=ws, input_threshold=int(config["input_threshold"])))
+        server.register(v, net.build(config, ws))
     if traffic["mode"] != "online":
         server.predict_many(inputs["blocks"][0])
         return server, None
@@ -197,17 +210,22 @@ def _counters(reg) -> dict:
 
 class RunData:
     """What a per-layer reader may read: the window, the trace, the
-    program's counters and spans over the window, and the work model."""
+    program's counters and spans over the window, the configuration as run
+    (`config`; `widths` and `slot_capacity` from it), its net module (`net`:
+    `net.ops`, `net.bytes_moved` and `net.min_seconds` of
+    `(config, rows, versions)` price a call) and the dense-chain work model
+    (`work`)."""
 
     work = work
 
     def __init__(self, mode, window_s, trace, before, after, spans, lag_s, completed,
-                 widths, versions, slot_capacity, peak):
+                 config, net, versions, peak):
         self.mode, self.window_s, self.trace = mode, window_s, trace
         self._before, self._after, self.spans = before, after, spans
         self.lag_s, self.completed = lag_s, completed
-        self.widths, self.versions, self.slot_capacity, self.peak = (
-            widths, versions, slot_capacity, peak)
+        self.config, self.net, self.versions, self.peak = config, net, versions, peak
+        self.widths = config.get("widths")
+        self.slot_capacity = int(config["slot_capacity"])
 
     def _match(self, kind, name, labels):
         for key, v in self._after.items():
@@ -250,7 +268,7 @@ def run_cell(args, *, root: Path = ROOT, require_chip: bool = True) -> dict | No
     """One run of one cell; returns the result object, or None where the
     chips the cell asks for are not there."""
     spec = load_cell(root, args.workload)
-    cell, config, traffic = spec["cell"], spec["config"], spec["traffic"]
+    cell, config, traffic, net = spec["cell"], spec["config"], spec["traffic"], spec["net"]
 
     import jax
 
@@ -270,15 +288,15 @@ def run_cell(args, *, root: Path = ROOT, require_chip: bool = True) -> dict | No
     from repro.netgen import telemetry
     from repro.netgen.engine import QueueFullError
 
-    widths, thr = config["widths"], int(config["input_threshold"])
     versions = make_versions(root, config, int(traffic["versions"]))
     names = [v for v, _ in versions]
     try:
-        inputs = generator.make_inputs(traffic, widths[0], names, args.seconds, args.seed)
+        inputs = generator.make_inputs(traffic, net.row_length(config), names, args.seconds,
+                                       args.seed)
     except generator.MixError as e:
         raise LayoutError(f"traffic {cell['traffic']}: {e}") from e
     online = traffic["mode"] == "online"
-    server, engine = serve(root, config, traffic, versions, inputs)
+    server, engine = serve(root, config, net, traffic, versions, inputs)
     pool_rows = list(inputs["pool"]) if online else None
     gc.collect()
 
@@ -344,7 +362,7 @@ def run_cell(args, *, root: Path = ROOT, require_chip: bool = True) -> dict | No
         gap = 0.0
         for k, v in enumerate(names):
             mine = ok & (inputs["ver"] == k)
-            ref = ref_mod.logits(weights[v], thr, inputs["pool"])
+            ref = ref_mod.logits(weights[v], config, inputs["pool"])
             gap = max(gap, ref_mod.widest_gap(ref[inputs["idx"][mine]], served[mine]))
         attempted, completed = n, int(ok.sum())
         lag_s = rec["t_sent"] - rec["due"]
@@ -352,7 +370,7 @@ def run_cell(args, *, root: Path = ROOT, require_chip: bool = True) -> dict | No
         window_s = rec["t_closed"] - rec["t0"]
     else:
         failed = unanswered = errored = 0
-        refs = [{v: ref_mod.logits(weights[v], thr, blk[v]) for v in names}
+        refs = [{v: ref_mod.logits(weights[v], config, blk[v]) for v in names}
                 for blk in inputs["blocks"]]
         gap, completed = 0.0, 0
         for b, out in rec["outs"]:
@@ -372,7 +390,7 @@ def run_cell(args, *, root: Path = ROOT, require_chip: bool = True) -> dict | No
 
     if args.trace:
         run = RunData(traffic["mode"], window_s, tr, before, after, spans, lag_s, completed,
-                      widths, len(names), int(config["slot_capacity"]), peak)
+                      config, net, len(names), peak)
         metrics = {}
         for mname, unit, mod in spec["per_layer"]:
             value = mod.read(run)

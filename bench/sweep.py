@@ -85,9 +85,9 @@ def main(argv=None) -> int:
 
     versions = run.make_versions(ROOT, config, int(traffic["versions"]))
     names = [v for v, _ in versions]
-    n_in = config["widths"][0]
+    n_in = spec["net"].row_length(config)
     inputs = generator.make_inputs(traffic, n_in, names, 0.01, args.seed)
-    _, engine = run.serve(ROOT, config, traffic, versions, inputs)
+    _, engine = run.serve(ROOT, config, spec["net"], traffic, versions, inputs)
     print(json.dumps({"setup_s": time.perf_counter() - T_START}), flush=True)
     knee, below_ok = None, True
     try:

@@ -61,3 +61,12 @@ def run_tiny(root: Path, workload: str, seed: int = 7, seconds: float = 1.0,
     args = run.parse_args(["--workload", workload, "--seed", str(seed),
                            "--seconds", str(seconds), "--trace", str(trace)])
     return run.run_cell(args, root=root, require_chip=False)
+
+
+def dense_chain(widths, slot_capacity: int = 256) -> tuple:
+    """(config, net module) of a dense chain at `widths`, as a per-layer
+    reader gets them on `RunData`."""
+    from bench import run
+
+    config = {"widths": list(widths), "input_threshold": 128, "slot_capacity": slot_capacity}
+    return config, run.load_net(ROOT, config)

@@ -90,7 +90,8 @@ def test_reference_is_exact_integer_arithmetic():
     x = rng.integers(0, 256, (64, 784), dtype=np.uint8)
     a = (x > 128).astype(np.int64)
     h = (a @ ws[0] > 0).astype(np.int64)
-    np.testing.assert_array_equal(reference.logits(ws, 128, x), h @ ws[1])
-    assert reference.widest_gap(reference.logits(ws, 128, x),
+    config = {"input_threshold": 128}
+    np.testing.assert_array_equal(reference.logits(ws, config, x), h @ ws[1])
+    assert reference.widest_gap(reference.logits(ws, config, x),
                                 (h @ ws[1]).argmax(axis=1)) == 0
     assert reference.widest_gap(np.zeros((2, 10)), np.array([0, 10])) == float("inf")

@@ -9,7 +9,8 @@ import pytest
 
 from bench import program_trace, trace, work
 from bench.run import RunData, load_file
-from bench.tests.conftest import TINY_OFFLINE, TINY_ONLINE, TINY_STACKED, run_tiny
+from bench.tests.conftest import (TINY_OFFLINE, TINY_ONLINE, TINY_STACKED, dense_chain,
+                                  run_tiny)
 
 DATA = Path(__file__).resolve().parent / "data"
 RECORDED = DATA / "paper-predict-many.xplane.pb"
@@ -46,9 +47,11 @@ def test_program_without_annotated_spans_reads_nothing(tmp_path):
     window, evs = program_trace._scan(RECORDED)
     recorded = trace.read(RECORDED)
     assert window == recorded.window and evs == []
-    run = RunData("offline", 2.0, recorded, {}, {}, [], None, 1, [784, 500, 10], 1, 256, V5E)
+    run = RunData("offline", 2.0, recorded, {}, {}, [], None, 1, *dense_chain([784, 500, 10]),
+                  1, V5E)
     assert program_trace.events(run, tmp_path) == []
-    untraced = RunData("offline", 2.0, None, {}, {}, [], None, 1, [784, 500, 10], 1, 256, V5E)
+    untraced = RunData("offline", 2.0, None, {}, {}, [], None, 1, *dense_chain([784, 500, 10]),
+                       1, V5E)
     for stem in NEW:
         assert _reader(stem).read(run) is None
         assert _reader(stem).read(untraced) is None
@@ -62,7 +65,7 @@ def test_program_without_annotated_spans_reads_nothing(tmp_path):
 ])
 def test_existing_readers_keep_their_values_on_the_recorded_trace(stem, value):
     run = RunData("offline", 2.0, trace.read(RECORDED), {}, {}, [], None, 1_000_000,
-                  [784, 500, 10], 1, 256, V5E)
+                  *dense_chain([784, 500, 10]), 1, V5E)
     assert _reader(stem).read(run) == pytest.approx(value, rel=1e-12)
 
 
@@ -72,7 +75,7 @@ def test_server_host_reader_keeps_its_value_on_the_recorded_trace():
              ("counter", "netgen_kernel_launches_total", (("form", "fusednet"),)): 12}
     spans = [SimpleNamespace(name="netgen.dispatch", duration_s=0.005)] * 3
     run = RunData("offline", 2.0, trace.read(RECORDED), {}, after, spans, None, 1_000_000,
-                  [784, 500, 10], 1, 256, V5E)
+                  *dense_chain([784, 500, 10]), 1, V5E)
     got = _reader("server_host_us_per_round").read(run)
     assert got == pytest.approx((0.015 - 0.000548308) / 12 * 1e6, rel=1e-9)
 

@@ -5,6 +5,7 @@ from pathlib import Path
 import pytest
 
 from bench.run import RunData, load_file
+from bench.tests.conftest import dense_chain
 
 READER = Path(__file__).resolve().parents[1] / "metrics" / "rounds_per_launch.py"
 LAUNCHES = ("counter", "netgen_kernel_launches_total", (("form", "fusednet"),))
@@ -13,7 +14,7 @@ ROUNDS = ("counter", "netgen_slot_rounds_total", (("server", "server-1"),))
 
 def _run(before, after):
     return RunData("offline", 2.0, None, before, after, [], None, 8192 * 10,
-                   [784, 1024, 1024, 1024, 10], 1, 256, None)
+                   *dense_chain([784, 1024, 1024, 1024, 10]), 1, None)
 
 
 @pytest.mark.parametrize("before, after, value", [

@@ -7,6 +7,7 @@ import pytest
 
 from bench import trace, work
 from bench.run import RunData
+from bench.tests.conftest import dense_chain
 
 DATA = Path(__file__).resolve().parent / "data"
 RECORDED = DATA / "paper-predict-many.xplane.pb"
@@ -79,7 +80,8 @@ def test_roofline_and_mfu_against_hand_computed_values(recorded):
     least, bound = work.min_seconds(PAPER, 256, 1, V5E)
     assert bound == "hbm"
     assert least == pytest.approx(598_728 / 819e9)
-    run = RunData("offline", 2.0, recorded, {}, {}, [], None, 1_000_000, PAPER, 1, 256, V5E)
+    run = RunData("offline", 2.0, recorded, {}, {}, [], None, 1_000_000, *dense_chain(PAPER),
+                  1, V5E)
     from bench.run import load_file
 
     metrics = Path(__file__).resolve().parents[1] / "metrics"

@@ -8,8 +8,8 @@ from __future__ import annotations
 import numpy as np
 
 
-def make(rng: np.random.Generator, widths, params: dict) -> list:
-    bound = int(params["bound"])
+def make(rng: np.random.Generator, config: dict, params: dict) -> list:
+    widths, bound = config["widths"], int(params["bound"])
     out = []
     for k, n in zip(widths[:-1], widths[1:]):
         w = rng.standard_normal((k, n))

@@ -4,6 +4,7 @@ from __future__ import annotations
 import numpy as np
 
 
-def make(rng: np.random.Generator, widths, params: dict) -> list:
+def make(rng: np.random.Generator, config: dict, params: dict) -> list:
+    widths = config["widths"]
     return [np.where(rng.standard_normal((k, n)) >= 0, 1, -1).astype(np.int32)
             for k, n in zip(widths[:-1], widths[1:])]

@@ -40,8 +40,14 @@ def peaks(device_kind: str, path: Path = PEAKS_FILE) -> dict:
     return table[device_kind]
 
 
+def least_seconds(n_ops: int, n_bytes: int, peak: dict) -> tuple[float, str]:
+    """Least time the chip could take for `n_ops` int8 operations over
+    `n_bytes` of HBM traffic, and which bound sets it."""
+    t_ops = n_ops / peak["int8_ops_per_s"]
+    t_mem = n_bytes / peak["hbm_bytes_per_s"]
+    return (t_ops, "int8") if t_ops >= t_mem else (t_mem, "hbm")
+
+
 def min_seconds(widths, rows: int, versions: int, peak: dict) -> tuple[float, str]:
     """Least time the chip could take for one call, and which bound sets it."""
-    t_ops = ops(widths, rows, versions) / peak["int8_ops_per_s"]
-    t_mem = bytes_moved(widths, rows, versions) / peak["hbm_bytes_per_s"]
-    return (t_ops, "int8") if t_ops >= t_mem else (t_mem, "hbm")
+    return least_seconds(ops(widths, rows, versions), bytes_moved(widths, rows, versions), peak)
