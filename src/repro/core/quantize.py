@@ -72,7 +72,13 @@ def weights_digest(weights, input_threshold: int = INPUT_THRESHOLD) -> str:
     hashing, so the digest is identical across storage dtypes (an int8
     and an int32 copy of the same matrix hash equal) and across processes
     and machines (sha256 over little-endian bytes, no Python `hash`).
+    A `repro.core.convnet.ConvNet` digests itself (`ConvNet.digest`:
+    layer kinds, shapes, weights, thresholds and input mode).
     """
+    from repro.core.convnet import ConvNet
+
+    if isinstance(weights, ConvNet):
+        return weights.digest()
     h = hashlib.sha256()
     weights = list(weights)
     h.update(f"netgen-v1:thr={int(input_threshold)}:depth={len(weights)}"

@@ -295,16 +295,18 @@ def argmax_lanes(scores: jnp.ndarray) -> jnp.ndarray:
 
 
 def _forward_planes_kernel(x_ref, *refs, threshold: int, layers, n_classes: int,
-                           bkw, stacked: bool):
+                           bkw, stacked: bool, unit_thresholds: bool = False):
     """One batch tile through the whole net. x: (bm, K) raw uint8 (leading
     model axis of size 1 when stacked); per layer l, refs hold pos_l then
-    neg_l uint32 (P_l, W_l, N_l) bit-planes, fully resident; o: (bm, 1)
-    int32 predicted class (a column: Mosaic refuses a rank-1 block under
-    128 rows). Activations live in registers/VMEM for the whole sweep —
-    the only HBM traffic per grid step is the input tile and the
-    predictions."""
+    neg_l uint32 (P_l, W_l, N_l) bit-planes, fully resident; with
+    `unit_thresholds`, then one int32 (1, N_l) threshold row per layer;
+    o: (bm, 1) int32 predicted class (a column: Mosaic refuses a rank-1
+    block under 128 rows). Activations live in registers/VMEM for the
+    whole sweep — the only HBM traffic per grid step is the input tile
+    and the predictions."""
     o_ref = refs[-1]
-    plane_refs = refs[:-1]
+    plane_refs = refs[:2 * len(layers)]
+    thr_refs = refs[2 * len(layers):-1] if unit_thresholds else None
     x = x_ref[...]
     if stacked:
         x = x[0]
@@ -327,7 +329,13 @@ def _forward_planes_kernel(x_ref, *refs, threshold: int, layers, n_classes: int,
                 d = jnp.sum(cp.astype(jnp.int32) - cn.astype(jnp.int32),
                             axis=1)
                 acc = acc + (d << b)
-        if out_words is not None:       # strict step + repack, in-register
+        if thr_refs is not None:        # step at acc > t; scores acc - t
+            t = thr_refs[li][...]
+            if out_words is not None:
+                a = _pack_bits_block(acc > t, out_words)
+            else:
+                acc = acc - t
+        elif out_words is not None:     # strict step + repack, in-register
             a = _pack_bits_block(acc > 0, out_words)
     # Slice to the real class count before argmax: a zero-padded class
     # column must never win when every real score is negative.
@@ -346,6 +354,7 @@ def binary_forward_planes(
     bm: int = 32,
     bkw: int | None = 8,
     interpret: bool | None = None,
+    thresholds: tuple | None = None,
 ) -> jnp.ndarray:
     """Whole-net forward in ONE pallas_call: raw uint8 images -> class ids.
 
@@ -362,6 +371,11 @@ def binary_forward_planes(
     double-buffers the input-tile DMA against compute. `bkw` chunks the
     word axis of each popcount (bounding the (bm, ck, N) intermediate);
     None means whole-width.
+
+    `thresholds` (single net only): one int32 (1, N_l) row per layer;
+    hidden layers then step at `acc > t` and the argmax ranks `acc - t`.
+    None (every threshold 0) adds no operand: the kernel is the one a
+    net without thresholds always ran.
     """
     assert planes and len(planes) % 2 == 0, len(planes)
     stacked = x.ndim == 3
@@ -385,9 +399,14 @@ def binary_forward_planes(
     assert layers[0][1] * 32 >= K, (layers[0], K)
     bm = min(bm, _rup(B))
     Bp = _pad_to(B, bm)
+    extra = {}
+    if thresholds is not None:
+        assert not stacked, "per-unit thresholds serve a single net"
+        assert len(thresholds) == len(layers), (len(thresholds), len(layers))
+        extra["unit_thresholds"] = True
     kern = functools.partial(
         _forward_planes_kernel, threshold=threshold, layers=tuple(layers),
-        n_classes=n_classes, bkw=bkw, stacked=stacked)
+        n_classes=n_classes, bkw=bkw, stacked=stacked, **extra)
     if stacked:
         xp = jnp.zeros((M, Bp, K), jnp.uint8).at[:, :B].set(
             x.astype(jnp.uint8))
@@ -409,6 +428,10 @@ def binary_forward_planes(
     for P, W, N, _ in layers:
         spec = pl.BlockSpec((P, W, N), lambda i: (0, 0, 0))
         in_specs += [spec, spec]
+    thr_args = ()
+    if thresholds is not None:
+        thr_args = tuple(jnp.asarray(t, jnp.int32) for t in thresholds)
+        in_specs += [pl.BlockSpec(t.shape, lambda i: (0, 0)) for t in thr_args]
     out = pl.pallas_call(
         kern,
         grid=(Bp // bm,),
@@ -416,7 +439,7 @@ def binary_forward_planes(
         out_specs=pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
         interpret=resolve_interpret(interpret),
-    )(xp, *planes)
+    )(xp, *planes, *thr_args)
     return out[:B, 0]
 
 

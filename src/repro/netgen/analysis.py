@@ -47,6 +47,13 @@ machine-checked invariant layer that replaces them:
       blocks and clamp-duplicates so `KernelTuner` never spends a
       measurement on a candidate that cannot change the outcome.
 
+  Layer-level (ConvNet) circuits — TensorInput, Conv, MaxPool and
+      Dense nodes: the verifier checks each layer's source and shapes,
+      the dataflow bounds every layer's accumulators per unit, and
+      `check_ranges` certifies the conv kernel's MXU products exact:
+      int8 weights (|w| <= 127), int8 activations ({0, 1}, or pixels
+      taken as x - 128), int32 accumulators that cannot overflow.
+
   Stack compatibility — `diagnose_stack`: the structured report of WHY
       a set of model versions cannot share one stacked dispatch
       (irregular circuit, depth/threshold/input/class disagreement),
@@ -82,8 +89,9 @@ from typing import Callable, Mapping, Sequence
 import numpy as np
 
 from repro.netgen.graph import (
-    Argmax, Circuit, InputCompare, IrregularCircuitError, SignStep,
-    WeightedSum, signed_width,
+    LAYER_NODES, Argmax, Circuit, Conv, Dense, InputCompare,
+    IrregularCircuitError, MaxPool, SignStep, TensorInput, WeightedSum,
+    argmax_classes, argmax_scores, eval_layer, layer_kind, signed_width,
 )
 from repro.netgen.plan import (
     ARGMAX, PACK_LANES, STEP, ExecutionPlan, lower_circuit,
@@ -199,17 +207,20 @@ def verify_circuit(circuit: Circuit, *, after_pass: str | None = None,
     # defined at this point of the topological sweep)
     max_id = max((n.id for n in circuit.nodes if n.id >= 0), default=-1)
     kind = np.zeros(max_id + 1, np.int8)
-    _BIT, _SUM, _ARGMAX = 1, 2, 3
+    _BIT, _SUM, _ARGMAX, _LAYER = 1, 2, 3, 4
 
     terms = _extract_terms(circuit) if _terms is None else _terms
     seen: dict[int, object] = {}
+    shapes: dict[int, tuple] = {}       # layer node id -> output shape
     step_of: dict[int, int] = {}        # sum id -> step id
     pixels: dict[int, int] = {}         # pixel index -> node id
     for i, n in enumerate(circuit.nodes):
         if n.id in seen:
             bad("structure.duplicate-id", f"node id {n.id} defined twice",
                 n.id)
-        if isinstance(n, InputCompare):
+        if isinstance(n, LAYER_NODES):
+            _verify_layer(n, seen, shapes, circuit, bad)
+        elif isinstance(n, InputCompare):
             if not 0 <= n.pixel < circuit.n_inputs:
                 bad("structure.input-pixel",
                     f"pixel {n.pixel} outside [0, {circuit.n_inputs})", n.id)
@@ -238,6 +249,10 @@ def verify_circuit(circuit: Circuit, *, after_pass: str | None = None,
                 for s in sorted(set(srcs[kinds == _ARGMAX].tolist())):
                     bad("structure.term-src",
                         f"term reads the Argmax node {s}", n.id)
+            if np.any(kinds == _LAYER):
+                for s in sorted(set(srcs[kinds == _LAYER].tolist())):
+                    bad("structure.term-src",
+                        f"term reads the layer-level node {s}", n.id)
         elif isinstance(n, SignStep):
             src = seen.get(n.src)
             if src is None:
@@ -263,14 +278,20 @@ def verify_circuit(circuit: Circuit, *, after_pass: str | None = None,
                 if src is None:
                     bad("structure.topo-order",
                         f"reads node {s} before it is defined", n.id)
+                elif isinstance(src, Dense) and not src.step:
+                    if len(n.srcs) != 1:
+                        bad("structure.argmax-src",
+                            "an argmax over a Dense layer's scores reads "
+                            "nothing else", n.id)
                 elif not isinstance(src, WeightedSum):
                     bad("structure.argmax-src",
                         f"score {s} is {type(src).__name__}, "
-                        "not a WeightedSum", n.id)
+                        "not a WeightedSum or a scoring Dense", n.id)
         seen[n.id] = n
         if 0 <= n.id <= max_id:
             kind[n.id] = (_SUM if isinstance(n, WeightedSum)
-                          else _ARGMAX if isinstance(n, Argmax) else _BIT)
+                          else _ARGMAX if isinstance(n, Argmax)
+                          else _LAYER if isinstance(n, LAYER_NODES) else _BIT)
 
     out = seen.get(circuit.output)
     if out is None or not isinstance(out, Argmax):
@@ -282,6 +303,55 @@ def verify_circuit(circuit: Circuit, *, after_pass: str | None = None,
         if post is not None:
             post(circuit, bad, terms)
     return _finish(diags, collect)
+
+
+def _verify_layer(n, seen: dict, shapes: dict, circuit: Circuit, bad) -> None:
+    """Source and shape checks of one layer-level node; records its
+    output shape in `shapes`."""
+    if isinstance(n, TensorInput):
+        if len(n.shape) != 3 or min(n.shape) < 1 \
+                or int(np.prod(n.shape)) != circuit.n_inputs:
+            bad("structure.input-shape",
+                f"image {n.shape} is not {circuit.n_inputs} inputs", n.id)
+        if n.mode not in ("compare", "pixels"):
+            bad("structure.input-mode", f"unknown input mode {n.mode!r}", n.id)
+        if not 0 <= n.threshold <= 255:
+            bad("structure.input-threshold",
+                f"threshold {n.threshold} outside the uint8 range", n.id)
+        shapes[n.id] = tuple(n.shape)
+        return
+    src = shapes.get(n.src)
+    if src is None:
+        what = "before it is defined" if n.src not in seen else "a node with no map"
+        bad("structure.layer-src", f"reads node {n.src}: {what}", n.id)
+        return
+    if isinstance(n, (Conv, Dense)):
+        if n.layer < 1:
+            bad("structure.sum-layer", f"layer tag {n.layer} < 1", n.id)
+        if n.thresholds.shape != (n.weights.shape[-1],):
+            bad("structure.thresholds",
+                f"{n.thresholds.shape} thresholds for {n.weights.shape[-1]} units",
+                n.id)
+    if isinstance(n, Conv):
+        if len(src) != 3 or n.weights.ndim != 4 or n.weights.shape[2] != src[2] \
+                or n.weights.shape[0] > src[0] or n.weights.shape[1] > src[1]:
+            bad("structure.conv-shape",
+                f"kernel {n.weights.shape} does not fit the {src} map", n.id)
+            return
+        kh, kw, _, cout = n.weights.shape
+        shapes[n.id] = (src[0] - kh + 1, src[1] - kw + 1, cout)
+    elif isinstance(n, MaxPool):
+        if len(src) != 3 or n.size < 1 or n.size > min(src[:2]):
+            bad("structure.pool-shape", f"{n.size}x{n.size} pool of a {src} map", n.id)
+            return
+        shapes[n.id] = (src[0] // n.size, src[1] // n.size, src[2])
+    else:
+        k = int(np.prod(src))
+        if n.weights.ndim != 2 or n.weights.shape[0] != k:
+            bad("structure.dense-shape",
+                f"weights {n.weights.shape} over {k} inputs", n.id)
+            return
+        shapes[n.id] = (n.weights.shape[1],)
 
 
 # -- per-pass postconditions (keyed by registry AND function name) ----------
@@ -358,8 +428,11 @@ class NodeRange:
 class RangeAnalysis:
     """The full per-node range map for one circuit, with the
     `value_bounds`/`node_widths`-compatible views the Verilog and cost
-    backends consume (so wire widths come from ONE analysis)."""
+    backends consume (so wire widths come from ONE analysis).
+    `units` holds, for each Conv and Dense node, the per-unit interval
+    (lo, hi) of its accumulator before its threshold."""
     ranges: dict[int, NodeRange]
+    units: dict = dataclasses.field(default_factory=dict)
 
     def __getitem__(self, nid: int) -> NodeRange:
         return self.ranges[nid]
@@ -379,8 +452,16 @@ class RangeAnalysis:
         out = circuit.node(circuit.output)
         if not isinstance(out, Argmax):
             return ()
-        return tuple((self.ranges[s].lo, self.ranges[s].hi)
-                     for s in out.srcs)
+        env: list = []
+        for s in out.srcs:
+            src = circuit.node(s)
+            if isinstance(src, Dense) and s in self.units:
+                lo, hi = self.units[s]
+                t = np.asarray(src.thresholds, np.int64)
+                env += list(zip((lo - t).tolist(), (hi - t).tolist()))
+            else:
+                env.append((self.ranges[s].lo, self.ranges[s].hi))
+        return tuple(env)
 
 
 def analyze_ranges(circuit: Circuit, *,
@@ -398,8 +479,13 @@ def analyze_ranges(circuit: Circuit, *,
     lo_a = np.zeros(max_id + 1, np.int64)
     hi_a = np.zeros(max_id + 1, np.int64)
     bd_a = np.zeros(max_id + 1, np.int64)
+    units: dict = {}
     for i, n in enumerate(circuit.nodes):
-        if isinstance(n, (InputCompare, SignStep)):
+        if isinstance(n, LAYER_NODES):
+            r = _layer_range(n, ranges, units)
+            if r is not None:
+                ranges[n.id] = r
+        elif isinstance(n, (InputCompare, SignStep)):
             ranges[n.id] = NodeRange(lo=0, hi=1, bound=1, width=1)
             if 0 <= n.id <= max_id:
                 hi_a[n.id] = bd_a[n.id] = 1
@@ -418,11 +504,39 @@ def analyze_ranges(circuit: Circuit, *,
             if 0 <= n.id <= max_id:
                 lo_a[n.id], hi_a[n.id], bd_a[n.id] = lo, hi, bound
         elif isinstance(n, Argmax):
-            k = len(n.srcs)
+            k = argmax_classes(circuit, n)
             ranges[n.id] = NodeRange(
                 lo=0, hi=max(k - 1, 0), bound=max(k - 1, 1),
                 width=max(math.ceil(math.log2(max(k, 2))), 1))
-    return RangeAnalysis(ranges=ranges)
+    return RangeAnalysis(ranges=ranges, units=units)
+
+
+_BITS = NodeRange(lo=0, hi=1, bound=1, width=1)
+
+
+def _layer_range(n, ranges: dict, units: dict) -> NodeRange | None:
+    """The value range of one layer-level node (None where its source has
+    none: structural breakage is the verifier's to report). A Conv or
+    Dense node's per-unit accumulator intervals go into `units`."""
+    if isinstance(n, TensorInput):
+        return NodeRange(lo=0, hi=255, bound=255, width=signed_width(255)) \
+            if n.mode == "pixels" else _BITS
+    src = ranges.get(n.src)
+    if src is None:
+        return None
+    if isinstance(n, MaxPool):
+        return src
+    w = np.asarray(n.weights, np.int64)
+    w = w.reshape(-1, w.shape[-1])
+    lo = np.where(w >= 0, w * src.lo, w * src.hi).sum(axis=0)
+    hi = np.where(w >= 0, w * src.hi, w * src.lo).sum(axis=0)
+    units[n.id] = (lo, hi)
+    if isinstance(n, Conv) or n.step:
+        return _BITS
+    t = np.asarray(n.thresholds, np.int64)
+    slo, shi = int((lo - t).min(initial=0)), int((hi - t).max(initial=0))
+    bound = max(abs(slo), abs(shi))
+    return NodeRange(lo=slo, hi=shi, bound=bound, width=signed_width(bound))
 
 
 def check_ranges(circuit: Circuit, ranges: RangeAnalysis | None = None, *,
@@ -436,6 +550,8 @@ def check_ranges(circuit: Circuit, ranges: RangeAnalysis | None = None, *,
         ranges = analyze_ranges(circuit)
     diags: list[Diagnostic] = []
     for n in circuit.nodes:
+        if isinstance(n, (Conv, Dense)):
+            diags += _check_layer(circuit, n, ranges, stage)
         if not isinstance(n, WeightedSum):
             continue
         r = ranges.ranges.get(n.id)
@@ -456,6 +572,35 @@ def check_ranges(circuit: Circuit, ranges: RangeAnalysis | None = None, *,
                 message=f"magnitude bound {r.bound} exceeds int32 — the "
                         "popcount kernel's accumulator would overflow"))
     return _finish(diags, collect)
+
+
+def _check_layer(circuit: Circuit, n, ranges: RangeAnalysis,
+                 stage: str | None) -> list[Diagnostic]:
+    """int32 safety of a layer's accumulators and thresholds, and for a
+    Conv the certificate that the conv kernel's int8 MXU products are
+    exact: |w| <= 127, int8 activations (a pixel input is taken as
+    x - 128, which moves the accumulator by 128 * sum(w) per channel)."""
+    diags = []
+    lo, hi = ranges.units.get(n.id, (np.zeros(1, np.int64), np.zeros(1, np.int64)))
+    worst = max(int(np.abs(lo).max(initial=0)), int(np.abs(hi).max(initial=0)))
+    if isinstance(n, Conv):
+        w = np.asarray(n.weights, np.int64)
+        if int(np.abs(w).max(initial=0)) > 127:
+            diags.append(Diagnostic(
+                check="range.mxu-int8", stage=stage, node=n.id,
+                message="conv weight outside int8's [-127, 127]: the MXU "
+                        "product would not be exact"))
+        src = circuit.node(n.src)
+        if isinstance(src, TensorInput) and src.mode == "pixels":
+            worst = int(128 * np.abs(w).sum(axis=(0, 1, 2)).max(initial=0)) \
+                + int(np.abs(n.thresholds).max(initial=0))
+    t = int(np.abs(np.asarray(n.thresholds, np.int64)).max(initial=0))
+    if worst > INT32_MAX or t > INT32_MAX:
+        diags.append(Diagnostic(
+            check="range.int32", stage=stage, node=n.id,
+            message=f"accumulator bound {worst} or threshold {t} exceeds "
+                    "int32 — the kernel's accumulator would overflow"))
+    return diags
 
 
 def check_envelope(before: tuple, after: tuple, *, stage: str | None = None,
@@ -495,7 +640,9 @@ def check_observed(circuit: Circuit, x_uint8, *,
     vals: dict[int, np.ndarray] = {}
     diags: list[Diagnostic] = []
     for n in circuit.nodes:
-        if isinstance(n, InputCompare):
+        if isinstance(n, LAYER_NODES):
+            vals[n.id] = eval_layer(n, vals, x)
+        elif isinstance(n, InputCompare):
             vals[n.id] = (
                 x[:, n.pixel].astype(np.int64) > n.threshold).astype(np.int64)
         elif isinstance(n, WeightedSum):
@@ -509,8 +656,7 @@ def check_observed(circuit: Circuit, x_uint8, *,
                 v > 0 if step_semantics == "strict" else v >= 0
             ).astype(np.int64)
         elif isinstance(n, Argmax):
-            vals[n.id] = np.argmax(
-                np.stack([vals[s] for s in n.srcs], axis=1), axis=1)
+            vals[n.id] = np.argmax(argmax_scores(n, vals, x.shape[0]), axis=1)
         r = ranges.ranges[n.id]
         v = vals[n.id]
         lo, hi = int(v.min(initial=0)), int(v.max(initial=0))
@@ -550,9 +696,15 @@ def proof_summary(circuit: Circuit,
     if ranges is None:
         ranges = analyze_ranges(circuit)
     sums = [n for n in circuit.nodes if isinstance(n, WeightedSum)]
+    layered = [n for n in circuit.nodes if isinstance(n, (Conv, Dense))]
     layer_widths: dict[str, int] = {}
     max_abs = 0
     slack = 0
+    for n in layered:
+        lo, hi = ranges.units[n.id]
+        acc = max(int(np.abs(lo).max(initial=0)), int(np.abs(hi).max(initial=0)))
+        layer_widths[str(n.layer)] = signed_width(acc)
+        max_abs = max(max_abs, acc)
     for n in sums:
         r = ranges.ranges[n.id]
         key = str(n.layer)
@@ -569,14 +721,17 @@ def proof_summary(circuit: Circuit,
         "layer_widths": layer_widths,
         "slack_bits": slack,
         "int32_safe": all(
-            ranges.ranges[n.id].bound <= INT32_MAX for n in sums),
+            ranges.ranges[n.id].bound <= INT32_MAX for n in sums)
+        and not any(_check_layer(circuit, n, ranges, None) for n in layered),
+        "layer_nodes": len(layered),
         "verified": True,
     }
 
 
 def summary_row(summary: Mapping) -> str:
     """One-line rendering of a proof summary for `artifact.report()`."""
-    return (f"analysis: proved {summary['sum_nodes']} accumulators fit "
+    n = summary['sum_nodes'] + summary.get('layer_nodes', 0)
+    return (f"analysis: proved {n} accumulators fit "
             f"<= {summary['max_width']} bits (max |acc| "
             f"{summary['max_abs_acc']}, slack {summary['slack_bits']} bits, "
             f"int32_safe={str(bool(summary['int32_safe'])).lower()})")
@@ -609,6 +764,9 @@ def verify_plan(plan: ExecutionPlan, *, stage: str | None = None,
 
     if not plan.layers:
         bad("plan.empty", "plan has no layers")
+        return _finish(diags, collect)
+    if plan.conv:
+        _verify_conv_plan(plan, bad)
         return _finish(diags, collect)
 
     for i, layer in enumerate(plan.layers):
@@ -669,6 +827,42 @@ def verify_plan(plan: ExecutionPlan, *, stage: str | None = None,
         true_fan_in = layer.fan_out
         expect = padded(layer.fan_out)
     return _finish(diags, collect)
+
+
+def _verify_conv_plan(plan: ExecutionPlan, bad) -> None:
+    """A conv plan's chain: each layer reads the shape the one before it
+    gives, one threshold a unit, int32-safe columns; a step everywhere
+    but the last (dense, argmax) layer."""
+    shape: tuple = tuple(plan.input_shape or ())
+    for i, layer in enumerate(plan.layers):
+        want_act = STEP if i < plan.depth - 1 else ARGMAX
+        if layer.activation != want_act:
+            bad("plan.activation",
+                f"activation {layer.activation!r}, expected {want_act!r}", i)
+        if layer.kind != "dense" and tuple(layer.in_shape or ()) != shape:
+            bad("plan.chain", f"reads a {layer.in_shape} map, gets {shape}", i)
+            return
+        if layer.kind == "pool":
+            shape = (shape[0] // layer.size, shape[1] // layer.size, shape[2])
+            continue
+        units = layer.weights.shape[-1]
+        if layer.thresholds is None or layer.thresholds.shape != (units,):
+            bad("plan.thresholds", f"needs {units} thresholds", i)
+        if layer.kind == "conv":
+            kh, kw, cin, cout = layer.weights.shape
+            if cin != shape[2] or kh > shape[0] or kw > shape[1]:
+                bad("plan.chain", f"kernel {layer.weights.shape} on a {shape} map", i)
+                return
+            shape = (shape[0] - kh + 1, shape[1] - kw + 1, cout)
+            mags = np.abs(layer.weights.astype(np.int64)).sum(axis=(0, 1, 2))
+        else:
+            if layer.fan_in != int(np.prod(shape)):
+                bad("plan.chain", f"fan_in {layer.fan_in} != {int(np.prod(shape))}", i)
+                return
+            shape = (units,)
+            mags = np.abs(layer.weights.astype(np.int64)).sum(axis=0)
+        if int(mags.max(initial=0)) * 255 > INT32_MAX:
+            bad("plan.int32", "max column magnitude exceeds int32", i)
 
 
 def _verify_planes(layer, i: int, bad) -> None:
@@ -909,6 +1103,13 @@ def diagnose_stack(items: Sequence) -> StackReport:
         if isinstance(item, ExecutionPlan):
             plans.append(item)
             continue
+        kind = layer_kind(item)
+        if kind is not None:
+            diags.append(Diagnostic(
+                check="stack.layer-kind", stage=f"version {i}",
+                message=f"stacked dispatch serves dense chains; this version "
+                        f"has a {kind} layer"))
+            continue
         try:
             plans.append(lower_circuit(item))
         except IrregularCircuitError as e:
@@ -922,7 +1123,12 @@ def diagnose_stack(items: Sequence) -> StackReport:
         return StackReport(compatible=False, n_versions=len(items),
                            diagnostics=tuple(diags))
     for i, p in enumerate(plans):
-        if p.packed or p.stacked:
+        if p.conv:
+            diags.append(Diagnostic(
+                check="stack.layer-kind", stage=f"version {i}",
+                message="stacked dispatch serves dense chains; this version "
+                        "is a conv plan"))
+        elif p.packed or p.stacked:
             diags.append(Diagnostic(
                 check="stack.form", stage=f"version {i}",
                 message="stacking takes dense single-net plans"))
@@ -1036,7 +1242,7 @@ def _lint_entry(entry: Path, fmt: str, artifact_key, PipelineSpec,
         bad("store.artifact", "text artifact with no artifact.txt")
     if meta["kind"] == "callable":
         form = meta.get("plan_form") or "dense"
-        if form not in ("dense", "packed", "planes"):
+        if form not in ("dense", "packed", "planes", "conv"):
             bad("store.plan", f"unknown plan_form {form!r}")
         else:
             try:

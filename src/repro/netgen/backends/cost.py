@@ -36,8 +36,8 @@ import dataclasses
 import math
 
 from repro.netgen.graph import (
-    Argmax, Circuit, InputCompare, SignStep, WeightedSum, value_bounds,
-    signed_width,
+    Argmax, Circuit, InputCompare, SignStep, WeightedSum, refuse_layers,
+    signed_width, value_bounds,
 )
 
 __all__ = ["CellCounts", "CostReport", "compile_cost", "logic_cells"]
@@ -79,7 +79,8 @@ def logic_cells(circuit: Circuit, *, analysis=None) -> CellCounts:
     `repro.netgen.analysis.RangeAnalysis`: its proven widths are used
     directly instead of re-deriving them from `value_bounds` (the two
     agree by construction — the analysis subsumes the ad-hoc width
-    inference)."""
+    inference). Layer-level (conv) nodes are not priced: the model
+    prices per-unit logic, and the cost target refuses such circuits."""
     if analysis is not None:
         width = analysis.widths()
     else:
@@ -153,6 +154,7 @@ def compile_cost(circuit: Circuit, *, _pass_trace=None,
     `_analysis` is the driver's range analysis of the FINAL circuit;
     intermediate trace circuits differ structurally, so they are priced
     with freshly derived widths."""
+    refuse_layers(circuit, "the cost target")
     per_pass = tuple(
         (name, logic_cells(c)) for name, c in (_pass_trace or ()))
     return CostReport(final=logic_cells(circuit, analysis=_analysis),

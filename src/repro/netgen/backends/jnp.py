@@ -26,7 +26,9 @@ __all__ = ["compile_jnp", "compile_jnp_multi"]
 
 def compile_jnp(circuit: Circuit):
     """Return a jitted fn: uint8 images (B, n_in) -> int predictions (B,)."""
-    return _execute_plan(lower_circuit(circuit))
+    plan = lower_circuit(circuit)
+    plan.require_dense("the jnp target")
+    return _execute_plan(plan)
 
 
 def _execute_plan(plan: ExecutionPlan):

@@ -33,6 +33,12 @@ falling back to the per-layer chain if the plan has no megakernel
 view), and each predictor call is exactly one kernel launch, counted in
 `netgen_kernel_launches_total{form}`.
 
+A plan lowered from a ConvNet (`plan.conv`) runs the conv predictor
+whatever the form options say (the plan chooses it, not a flag): one
+jitted program per call of one `binary_conv` kernel (`netgen_conv`) per
+conv layer, each with its following 2x2 pool fused, then the dense
+tail on the megakernel with per-unit thresholds (`_build_convnet`).
+
 Block sizes (`bm`, `bn`, `bkw`) are declared target options; with
 `pallas[tuned=true]` they — and, when no form is forced, the
 dense/packed/planes/fusednet choice itself — are grid-searched per
@@ -56,7 +62,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.netgen.graph import Circuit, IrregularCircuitError
+from repro.netgen.graph import Circuit, IrregularCircuitError, LayerKindError
 from repro.netgen.plan import ExecutionPlan, lower_circuit
 
 __all__ = ["compile_pallas", "compile_pallas_multi", "compile_fused"]
@@ -258,11 +264,7 @@ def _build_fusednet(plan: ExecutionPlan, kw: dict, blocks: dict):
 
     view = plan.megakernel_view()
     arrays = tuple(jnp.asarray(a, jnp.uint32) for a in view.arrays)
-    kkw = dict(kw)
-    if blocks.get("bm") is not None:
-        kkw["bm"] = int(blocks["bm"])
-    if blocks.get("bkw") is not None:
-        kkw["bkw"] = int(blocks["bkw"])
+    kkw = _megakernel_kw(view, kw, blocks)
     jitted = jax.jit(lambda x: bmv.binary_forward_planes(
         x, *arrays, threshold=view.input_threshold,
         n_classes=view.n_classes, **kkw))
@@ -275,6 +277,81 @@ def _build_fusednet(plan: ExecutionPlan, kw: dict, blocks: dict):
                              datapath="fusednet", blocks=blocks, launches=1)
 
 
+def _megakernel_kw(view, kw: dict, blocks: dict) -> dict:
+    """The megakernel's keywords: interpret, the bm/bkw blocks, and the
+    view's thresholds only where it has some (none: today's kernel)."""
+    kkw = dict(kw)
+    if blocks.get("bm") is not None:
+        kkw["bm"] = int(blocks["bm"])
+    if blocks.get("bkw") is not None:
+        kkw["bkw"] = int(blocks["bkw"])
+    if view.thresholds is not None:
+        kkw["thresholds"] = tuple(jnp.asarray(t, jnp.int32) for t in view.thresholds)
+    return kkw
+
+
+def _build_convnet(plan: ExecutionPlan, kw: dict, blocks: dict):
+    """The conv-net predictor: one jitted program a call — the request
+    rows laid out as image rows, one `binary_conv` launch per conv layer
+    (a following 2x2 pool fused into it), then the dense tail on the
+    megakernel with per-unit thresholds. A first layer that reads pixels
+    takes `x - 128` in int8; its thresholds absorb `128 * sum(w)` per
+    channel. It counts one `convnet` launch a call, so launches and slot
+    rounds keep giving the rows a launch ran. Stacked conv plans are not
+    served (`stack_plans` refuses them)."""
+    from repro.kernels.binary_conv import binary_conv as bc
+    from repro.kernels.binary_matvec import ops as bmv
+    from repro.netgen import telemetry
+
+    convs = []
+    layers = plan.layers
+    i = 0
+    while i < len(layers) and layers[i].kind != "dense":
+        layer = layers[i]
+        if layer.kind != "conv":
+            raise LayerKindError(f"layer {i}: a {layer.kind} layer must follow a conv layer")
+        pool = i + 1 < len(layers) and layers[i + 1].kind == "pool"
+        if pool and layers[i + 1].size != 2:
+            raise LayerKindError(f"layer {i + 1}: only a 2x2 pool is fused")
+        assert layer.in_shape is not None and layer.thresholds is not None
+        kh, kwid, cin, cout = layer.weights.shape
+        geo = bc.conv_geometry(*layer.in_shape[:2], cin, cout, kh, kwid, pool)
+        t = layer.thresholds.astype(np.int64)
+        if not convs and plan.input_mode == "pixels":
+            t = t - 128 * layer.weights.astype(np.int64).sum(axis=(0, 1, 2))
+        convs.append((geo, jnp.asarray(bc.banded_weights(geo, layer.weights)),
+                      jnp.asarray(np.tile(t, geo.bo)[None], jnp.int32),
+                      jnp.asarray(bc.pool_matrix(geo)) if pool else None,
+                      bc.batch_tile(geo)))
+        i += 2 if pool else 1
+    view = plan.dense_tail().megakernel_view()
+    arrays = tuple(jnp.asarray(a, jnp.uint32) for a in view.arrays)
+    kkw = _megakernel_kw(view, kw, blocks)
+    tile = max((c[4] for c in convs), default=1)
+
+    def forward(x):
+        rows = x
+        if convs:
+            b = x.shape[0]
+            a = bc.image_rows(x, plan.input_shape, plan.input_mode,
+                              plan.input_threshold, -(-b // tile) * tile)
+            for geo, taps, thr, pool, bm in convs:
+                a = bc.binary_conv(a, taps, thr, pool, geo=geo, bm=bm, **kw)
+            rows = bc.flat_rows(a)[:b]
+        return bmv.binary_forward_planes(
+            rows, *arrays, threshold=view.input_threshold,
+            n_classes=view.n_classes, **kkw)
+
+    jitted = jax.jit(forward)
+
+    def predict(x_uint8):
+        telemetry.kernel_launches("convnet").inc()
+        return jitted(x_uint8)
+
+    return _finish_predictor(predict, jitted, plan_form="conv",
+                             datapath="convnet", blocks=blocks, launches=1)
+
+
 # ---------------------------------------------------------------------------
 # Autotuning (repro.netgen.tune)
 # ---------------------------------------------------------------------------
@@ -285,6 +362,7 @@ def _plan_signature(plan: ExecutionPlan) -> dict:
     planes kernel's work, so nets of equal shape but different weight
     ranges tune separately). Computed from magnitudes directly — no
     plane decomposition is materialized for keying."""
+    plan.require_dense("the kernel tuner and the explorer")
     return {
         "n_inputs": plan.n_inputs,
         "widths": [l.fan_out for l in plan.layers],
@@ -496,9 +574,17 @@ def compile_pallas(circuit: Circuit, *, interpret: bool | None = None,
     resolves the design-space explorer's persisted winner for this plan
     shape when one exists (see `repro.netgen.explore`); without a
     record it is inert.
+
+    A ConvNet plan builds the conv predictor (`_build_convnet`); the
+    tuner, the explorer and the packed datapath refuse it.
     """
     kw = {} if interpret is None else {"interpret": interpret}
     plan = lower_circuit(circuit)
+    if plan.conv:
+        if tuned or explored or packed:
+            opt = "tuned" if tuned else "explored" if explored else "packed"
+            plan.require_dense(f"pallas[{opt}=true]")
+        return _build_convnet(plan, kw, {"bm": bm, "bn": bn, "bkw": bkw})
     form, blocks, prebuilt = _resolve_datapath(
         plan, kw, packed=packed, planes=planes, fusednet=fusednet,
         tuned=tuned, bm=bm, bn=bn, bkw=bkw, tuner=_tuner, multi=False,
@@ -566,6 +652,7 @@ def compile_fused(circuit: Circuit, *, interpret: bool | None = None,
 
     kw = {} if interpret is None else {"interpret": interpret}
     plan = lower_circuit(circuit)
+    plan.require_dense("the fused target")
     if plan.depth != 2:
         raise IrregularCircuitError(
             f"fused backend supports exactly 2 layers, got {plan.depth}")

@@ -27,7 +27,7 @@ import math
 from repro.netgen.analysis import RangeAnalysis, analyze_ranges
 from repro.netgen.graph import (
     Argmax, Circuit, InputCompare, IrregularCircuitError, SignStep,
-    WeightedSum,
+    WeightedSum, refuse_layers,
 )
 from repro.netgen.plan import lower_circuit
 
@@ -93,6 +93,7 @@ def emit_verilog(
     callers get the same analysis computed here."""
     if style not in ("auto", "legacy", "generic"):
         raise ValueError(f"unknown style {style!r}")
+    refuse_layers(circuit, "the verilog target")
     if addend is None:
         addend = _is_addend_form(circuit)
     ranges = analyze_ranges(circuit) if _analysis is None else _analysis

@@ -70,7 +70,7 @@ import numpy as np
 
 from repro.netgen import telemetry
 from repro.netgen.backends.pallas import TUNE_BLOCKS
-from repro.netgen.graph import IrregularCircuitError
+from repro.netgen.graph import IrregularCircuitError, LayerKindError
 from repro.netgen.pipeline import PipelineSpec
 from repro.netgen.plan import lower_circuit
 from repro.netgen.targets import resolve_target, target_string
@@ -326,6 +326,7 @@ class Explorer:
                  strategy: str = "anneal", budget: int = 24, seed: int = 0,
                  batch: int = 256, reps: int = 2, cells_weight: float = 0.01,
                  interpret: bool | None = None, input_threshold=None):
+        from repro.core.convnet import ConvNet
         from repro.core.quantize import weights_digest
         from repro.netgen.frontend import _extract_weights
         from repro.netgen.tune import default_tuner
@@ -357,6 +358,10 @@ class Explorer:
         # content identity of each net (compile-free)
         self._digests = {}
         for name in self.space.nets:
+            if isinstance(self.nets[name], ConvNet):
+                raise LayerKindError(
+                    f"explore searches dense-chain datapaths; net {name!r} is a "
+                    "ConvNet with conv layers")
             ws, thr = _extract_weights(self.nets[name], input_threshold)
             self._digests[name] = weights_digest(ws, thr)
         self._bases: dict[tuple, _Base] = {}

@@ -3,6 +3,9 @@
 Accepts any of:
   * a `repro.core.quantize.QuantizedNet` (any depth — the class holds a
     tuple of integer weight matrices),
+  * a `repro.core.convnet.ConvNet` (conv, max-pool and dense layers with
+    per-channel thresholds; lowered to one layer-level node per layer,
+    see `lower_convnet`),
   * any object with `.weights` (sequence of 2-D int arrays) and
     `.input_threshold`,
   * a bare sequence of 2-D integer arrays (threshold passed separately).
@@ -19,8 +22,10 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.core.convnet import ConvLayer, ConvNet, DenseLayer, PoolLayer
 from repro.netgen.graph import (
-    Argmax, Circuit, InputCompare, SignStep, Term, WeightedSum,
+    Argmax, Circuit, Conv, Dense, InputCompare, MaxPool, SignStep,
+    TensorInput, Term, WeightedSum,
 )
 
 DEFAULT_INPUT_THRESHOLD = 128  # paper §III.B pixel cutoff
@@ -48,6 +53,11 @@ def _validate_threshold(thr) -> int:
 
 
 def _extract_weights(net, input_threshold):
+    """(weights, input threshold) of a dense stack, canonicalized; a
+    ConvNet passes through whole as its own weights (its threshold and
+    input mode are part of it)."""
+    if isinstance(net, ConvNet):
+        return net, net.input_threshold
     if hasattr(net, "weights"):
         ws = [np.asarray(w) for w in net.weights]
     elif hasattr(net, "w1") and hasattr(net, "w2"):
@@ -76,8 +86,42 @@ def _extract_weights(net, input_threshold):
     return ws, int(thr)
 
 
+def lower_convnet(net: ConvNet) -> Circuit:
+    """Lower a ConvNet into a layer-level circuit: a TensorInput, one
+    Conv / MaxPool / Dense node per layer (the last Dense gives scores,
+    not steps), and an Argmax over them — O(layers) nodes."""
+    pixels = net.input_mode == "pixels"
+    thr = 0 if pixels else net.input_threshold
+    nodes: list = [TensorInput(id=0, shape=net.input_shape, mode=net.input_mode,
+                               threshold=thr)]
+    weighted = 0
+    for i, layer in enumerate(net.layers):
+        nid, src = len(nodes), len(nodes) - 1
+        if isinstance(layer, PoolLayer):
+            nodes.append(MaxPool(id=nid, src=src, size=layer.size))
+            continue
+        weighted += 1
+        if isinstance(layer, ConvLayer):
+            nodes.append(Conv(id=nid, src=src, weights=layer.weights,
+                              thresholds=layer.thresholds, layer=weighted))
+        else:
+            assert isinstance(layer, DenseLayer), type(layer)
+            nodes.append(Dense(id=nid, src=src, weights=layer.weights,
+                               thresholds=layer.thresholds, layer=weighted,
+                               step=i < len(net.layers) - 1))
+    out = Argmax(id=len(nodes), srcs=(len(nodes) - 1,))
+    nodes.append(out)
+    circuit = Circuit(n_inputs=net.n_inputs, input_threshold=thr,
+                      nodes=tuple(nodes), output=out.id)
+    circuit.validate()
+    return circuit
+
+
 def lower(net, *, input_threshold: int | None = None) -> Circuit:
-    """Lower a quantized N-layer stack into a Circuit. See module doc."""
+    """Lower a quantized N-layer stack (or a ConvNet) into a Circuit.
+    See module doc."""
+    if isinstance(net, ConvNet):
+        return lower_convnet(net)
     ws, thr = _extract_weights(net, input_threshold)
     n_in = ws[0].shape[0]
 

@@ -13,6 +13,22 @@ optimization pass and every backend operates on:
   Argmax        — paper Fig. 6 line 15: the priority-mux comparison network
                   producing the predicted class index.
 
+A convolutional net (`repro.core.convnet.ConvNet`) lowers to layer-level
+nodes instead, one per layer, so its IR holds O(layers) objects rather
+than one `Term` per multiply-accumulate (about 59M for FINN's CNV):
+
+  TensorInput   — the (H, W, C) image of a request row, binarized by one
+                  threshold ("compare") or read as 8-bit values ("pixels").
+  Conv          — a valid convolution with one threshold per channel:
+                  `acc > t` -> a {0, 1} map.
+  MaxPool       — a max-pool of a {0, 1} map (an OR).
+  Dense         — a dense layer over the HWC-flattened input, `acc > t`
+                  (`step`) or the scores `acc - t` that feed the Argmax.
+
+The per-unit optimization passes leave layer nodes alone; the Verilog
+and cost backends, the tuner and the explorer refuse them
+(`LayerKindError`).
+
 Nodes are immutable and identified by dense integer ids; a `Circuit` is a
 topologically-ordered tuple of nodes. Every value-carrying node has a
 *signed bit-width* inferred exactly from the maximum magnitude it can
@@ -36,7 +52,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Iterable, Union
+from typing import Union
 
 import numpy as np
 
@@ -85,7 +101,89 @@ class Argmax:
     srcs: tuple[NodeId, ...]
 
 
-Node = Union[InputCompare, WeightedSum, SignStep, Argmax]
+@dataclasses.dataclass(frozen=True, eq=False)
+class TensorInput:
+    """The image input of a layer-level circuit: `shape` (H, W, C) read
+    HWC row-major from the uint8 request row. `mode` "compare" gives
+    `x > threshold` (1 bit a pixel); "pixels" gives the 8-bit values."""
+    id: NodeId
+    shape: tuple[int, int, int]
+    mode: str
+    threshold: int
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class Conv:
+    """Valid stride-1 convolution of the (H, W, C) map `src` by integer
+    `weights` (kh, kw, C, C_out), then `acc > thresholds[c]` -> {0, 1}.
+    `layer` numbers the weighted layers from 1."""
+    id: NodeId
+    src: NodeId
+    weights: np.ndarray
+    thresholds: np.ndarray
+    layer: int
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class MaxPool:
+    """Max over non-overlapping `size` x `size` windows of a map."""
+    id: NodeId
+    src: NodeId
+    size: int
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class Dense:
+    """Integer `weights` (k, n) over the HWC-flattened `src`; `step`:
+    `acc > thresholds` -> {0, 1}, else the scores `acc - thresholds`."""
+    id: NodeId
+    src: NodeId
+    weights: np.ndarray
+    thresholds: np.ndarray
+    layer: int
+    step: bool
+
+
+Node = Union[InputCompare, WeightedSum, SignStep, Argmax,
+             TensorInput, Conv, MaxPool, Dense]
+LAYER_NODES = (TensorInput, Conv, MaxPool, Dense)
+_LAYER_KIND = {TensorInput: "input", Conv: "conv", MaxPool: "pool", Dense: "dense"}
+
+
+def node_srcs(n: Node) -> tuple[NodeId, ...]:
+    """The ids a node reads."""
+    if isinstance(n, WeightedSum):
+        return tuple(t.src for t in n.terms)
+    if isinstance(n, (SignStep, Conv, MaxPool, Dense)):
+        return (n.src,)
+    if isinstance(n, Argmax):
+        return n.srcs
+    return ()
+
+
+class LayerKindError(ValueError):
+    """Raised where a component handles per-unit dense circuits or plans
+    only and meets a layer-level (conv) one. The message names the
+    component and the layer kind."""
+
+
+def layer_kind(circuit: "Circuit") -> str | None:
+    """The first weighted or pooling layer kind of a layer-level circuit
+    ("conv", "pool", "dense"), or None for a per-unit circuit."""
+    kinds = [_LAYER_KIND[type(n)] for n in circuit.nodes if isinstance(n, LAYER_NODES)]
+    if not kinds:
+        return None
+    return next((k for k in kinds if k in ("conv", "pool")), kinds[-1])
+
+
+def refuse_layers(circuit: "Circuit", what: str) -> None:
+    """Raise `LayerKindError` naming `what` and the layer kind when the
+    circuit is layer-level."""
+    kind = layer_kind(circuit)
+    if kind is not None:
+        raise LayerKindError(
+            f"{what} handles per-unit dense circuits; this circuit has a "
+            f"{kind} layer (a layer-level ConvNet circuit)")
 
 
 class IrregularCircuitError(ValueError):
@@ -123,22 +221,17 @@ class Circuit:
 
     @property
     def depth(self) -> int:
-        """Number of dense layers the circuit was lowered from."""
-        sums = self.by_kind(WeightedSum)
-        return max((n.layer for n in sums), default=0)
+        """Number of weighted layers the circuit was lowered from."""
+        tagged = [n.layer for n in self.nodes
+                  if isinstance(n, (WeightedSum, Conv, Dense))]
+        return max(tagged, default=0)
 
     def consumers(self) -> dict[NodeId, list[NodeId]]:
         """Map node id -> ids of nodes that read it."""
         out: dict[NodeId, list[NodeId]] = {n.id: [] for n in self.nodes}
         for n in self.nodes:
-            if isinstance(n, WeightedSum):
-                for t in n.terms:
-                    out[t.src].append(n.id)
-            elif isinstance(n, SignStep):
-                out[n.src].append(n.id)
-            elif isinstance(n, Argmax):
-                for s in n.srcs:
-                    out[s].append(n.id)
+            for s in node_srcs(n):
+                out[s].append(n.id)
         return out
 
     def validate(self) -> None:
@@ -153,15 +246,7 @@ class Circuit:
         for n in self.nodes:
             if n.id in seen:
                 raise ValueError(f"duplicate node id {n.id}")
-            if isinstance(n, WeightedSum):
-                srcs: Iterable[NodeId] = (t.src for t in n.terms)
-            elif isinstance(n, SignStep):
-                srcs = (n.src,)
-            elif isinstance(n, Argmax):
-                srcs = n.srcs
-            else:
-                srcs = ()
-            for s in srcs:
+            for s in node_srcs(n):
                 if s not in seen:
                     raise ValueError(
                         f"node {n.id} reads {s} before it is defined")
@@ -184,8 +269,24 @@ def value_bounds(circuit: Circuit) -> dict[NodeId, int]:
         elif isinstance(n, WeightedSum):
             bound[n.id] = sum(abs(t.weight) * bound[t.src] for t in n.terms)
         elif isinstance(n, Argmax):
-            bound[n.id] = max(len(n.srcs) - 1, 1)
+            bound[n.id] = max(argmax_classes(circuit, n) - 1, 1)
+        elif isinstance(n, TensorInput):
+            bound[n.id] = 255 if n.mode == "pixels" else 1
+        elif isinstance(n, (Conv, MaxPool)) or (isinstance(n, Dense) and n.step):
+            bound[n.id] = 1
+        elif isinstance(n, Dense):           # the scores acc - t
+            mag = np.abs(n.weights).sum(axis=0) * bound[n.src] + np.abs(n.thresholds)
+            bound[n.id] = int(mag.max(initial=0))
     return bound
+
+
+def argmax_classes(circuit: Circuit, n: Argmax) -> int:
+    """Classes an Argmax ranks: one per scalar source, n per Dense one."""
+    total = 0
+    for s in n.srcs:
+        src = circuit.node(s)
+        total += src.weights.shape[1] if isinstance(src, Dense) else 1
+    return total
 
 
 def signed_width(bound: int) -> int:
@@ -201,7 +302,8 @@ def node_widths(circuit: Circuit) -> dict[NodeId, int]:
         if isinstance(n, (InputCompare, SignStep)):
             widths[nid] = 1
         elif isinstance(n, Argmax):
-            widths[nid] = max(math.ceil(math.log2(max(len(n.srcs), 2))), 1)
+            k = argmax_classes(circuit, n)
+            widths[nid] = max(math.ceil(math.log2(max(k, 2))), 1)
         else:
             widths[nid] = signed_width(b)
     return widths
@@ -218,8 +320,10 @@ def as_layered_weights(circuit: Circuit) -> list[np.ndarray]:
     for l == 1), every hidden sum feeds exactly one SignStep, and the
     Argmax reads exactly the last layer's sums. Addend-rewritten circuits
     are fine (duplicate unit terms re-accumulate); shared/CSE circuits are
-    not and raise IrregularCircuitError.
+    not and raise IrregularCircuitError; layer-level circuits raise
+    LayerKindError.
     """
+    refuse_layers(circuit, "dense weight-matrix extraction")
     inputs = circuit.by_kind(InputCompare)
     sums = circuit.by_kind(WeightedSum)
     steps = circuit.by_kind(SignStep)
@@ -262,7 +366,10 @@ def as_layered_weights(circuit: Circuit) -> list[np.ndarray]:
 # Array codec (for the persistent ArtifactStore)
 # ---------------------------------------------------------------------------
 
-_KIND_CODES = {InputCompare: 0, WeightedSum: 1, SignStep: 2, Argmax: 3}
+_KIND_CODES = {InputCompare: 0, WeightedSum: 1, SignStep: 2, Argmax: 3,
+               TensorInput: 4, Conv: 5, MaxPool: 6, Dense: 7}
+_MODES = ("compare", "pixels")
+_LAYER_META = 8          # int64 fields a layer node keeps in `lay_meta`
 
 
 def circuit_to_arrays(circuit: Circuit) -> dict[str, np.ndarray]:
@@ -271,16 +378,31 @@ def circuit_to_arrays(circuit: Circuit) -> dict[str, np.ndarray]:
     persists via `np.savez`. Compact (terms are one (host_row, weight,
     src) int64 triple each, not a Python object) and code-free (no
     pickle: the store stays loadable across refactors and trustworthy
-    across processes). `circuit_from_arrays` is the exact inverse.
+    across processes). `circuit_from_arrays` is the exact inverse. A
+    layer-level node keeps one row of `lay_meta` (its source and shape),
+    its weights flattened into `lay_w` and its thresholds into `lay_t`.
     """
     kinds, ids = [], []
     cmp_pixel, cmp_thr = [], []
     sum_layer, sum_nterms, term_weight, term_src = [], [], [], []
     step_src, argmax_srcs, argmax_nsrcs = [], [], []
+    lay_meta: list[list[int]] = []
+    lay_w: list[np.ndarray] = []
+    lay_t: list[np.ndarray] = []
     for n in circuit.nodes:
         kinds.append(_KIND_CODES[type(n)])
         ids.append(n.id)
-        if isinstance(n, InputCompare):
+        if isinstance(n, LAYER_NODES):
+            if isinstance(n, TensorInput):
+                row = [*n.shape, _MODES.index(n.mode), n.threshold]
+            elif isinstance(n, MaxPool):
+                row = [n.src, n.size]
+            else:
+                row = [n.src, n.layer, int(getattr(n, "step", True)), *n.weights.shape]
+                lay_w.append(np.asarray(n.weights, np.int64).ravel())
+                lay_t.append(np.asarray(n.thresholds, np.int64).ravel())
+            lay_meta.append(row + [0] * (_LAYER_META - len(row)))
+        elif isinstance(n, InputCompare):
             cmp_pixel.append(n.pixel)
             cmp_thr.append(n.threshold)
         elif isinstance(n, WeightedSum):
@@ -304,6 +426,9 @@ def circuit_to_arrays(circuit: Circuit) -> dict[str, np.ndarray]:
         "term_weight": i64(term_weight), "term_src": i64(term_src),
         "step_src": i64(step_src),
         "argmax_nsrcs": i64(argmax_nsrcs), "argmax_srcs": i64(argmax_srcs),
+        "lay_meta": i64(lay_meta).reshape(-1, _LAYER_META),
+        "lay_w": np.concatenate(lay_w) if lay_w else i64([]),
+        "lay_t": np.concatenate(lay_t) if lay_t else i64([]),
     }
 
 
@@ -315,10 +440,33 @@ def circuit_from_arrays(arrays) -> Circuit:
         "sum_nterms", "term_weight", "term_src", "step_src",
         "argmax_nsrcs", "argmax_srcs")}
     n_inputs, input_threshold, output = (int(v) for v in a["header"])
+    layer_keys = ("lay_meta", "lay_w", "lay_t")
+    if all(k in getattr(arrays, "files", arrays) for k in layer_keys):
+        a.update({k: np.asarray(arrays[k]) for k in layer_keys})
     nodes: list[Node] = []
-    ci = si = ti = pi = ai = aj = 0
+    ci = si = ti = pi = ai = aj = li = wi = hi = 0
     for kind, nid in zip(a["kinds"].tolist(), a["ids"].tolist()):
-        if kind == 0:
+        if kind >= 4:
+            m = [int(v) for v in a["lay_meta"][li]]
+            li += 1
+            if kind == 4:
+                nodes.append(TensorInput(id=nid, shape=(m[0], m[1], m[2]),
+                                         mode=_MODES[m[3]], threshold=m[4]))
+                continue
+            if kind == 6:
+                nodes.append(MaxPool(id=nid, src=m[0], size=m[1]))
+                continue
+            shape = tuple(m[3:7] if kind == 5 else m[3:5])
+            size = int(np.prod(shape))
+            w = a["lay_w"][wi:wi + size].reshape(shape)
+            t = a["lay_t"][hi:hi + shape[-1]]
+            wi, hi = wi + size, hi + shape[-1]
+            if kind == 5:
+                nodes.append(Conv(id=nid, src=m[0], weights=w, thresholds=t, layer=m[1]))
+            else:
+                nodes.append(Dense(id=nid, src=m[0], weights=w, thresholds=t,
+                                   layer=m[1], step=bool(m[2])))
+        elif kind == 0:
             nodes.append(InputCompare(
                 id=nid, pixel=int(a["cmp_pixel"][ci]),
                 threshold=int(a["cmp_thr"][ci])))
@@ -378,7 +526,9 @@ def evaluate(
     vals: dict[NodeId, np.ndarray] = {}
     out = None
     for n in circuit.nodes:
-        if isinstance(n, InputCompare):
+        if isinstance(n, LAYER_NODES):
+            vals[n.id] = eval_layer(n, vals, x)
+        elif isinstance(n, InputCompare):
             vals[n.id] = (x[:, n.pixel].astype(np.int64) > n.threshold).astype(np.int64)
         elif isinstance(n, WeightedSum):
             acc = np.zeros(x.shape[0], dtype=np.int64)
@@ -393,8 +543,38 @@ def evaluate(
             v = vals[n.src]
             vals[n.id] = (v > 0 if step_semantics == "strict" else v >= 0).astype(np.int64)
         elif isinstance(n, Argmax):
-            stacked = np.stack([vals[s] for s in n.srcs], axis=1)
-            out = vals[n.id] = np.argmax(stacked, axis=1)
+            out = vals[n.id] = np.argmax(argmax_scores(n, vals, x.shape[0]), axis=1)
     if out is None:
         raise ValueError("circuit has no Argmax output node")
     return vals[circuit.output]
+
+
+def argmax_scores(n: Argmax, vals: dict, batch: int) -> np.ndarray:
+    """(B, classes) scores an Argmax ranks: its sources' values side by
+    side, a scalar source one column, a Dense source all of its units."""
+    return np.concatenate([vals[s].reshape(batch, -1) for s in n.srcs], axis=1)
+
+
+def eval_layer(n: Node, vals: dict, x: np.ndarray) -> np.ndarray:
+    """Exact int64 value of one layer-level node over the batch `x`."""
+    if isinstance(n, TensorInput):
+        img = x.reshape(x.shape[0], *n.shape).astype(np.int64)
+        return img if n.mode == "pixels" else (img > n.threshold).astype(np.int64)
+    a = vals[n.src]
+    if isinstance(n, MaxPool):
+        b, h, w, c = a.shape
+        k = n.size
+        a = a[:, :h // k * k, :w // k * k]
+        return a.reshape(b, h // k, k, w // k, k, c).max(axis=(2, 4))
+    if isinstance(n, Conv):
+        kh, kw = n.weights.shape[:2]
+        ho, wo = a.shape[1] - kh + 1, a.shape[2] - kw + 1
+        acc = np.zeros((a.shape[0], ho, wo, n.weights.shape[3]), np.int64)
+        for dy in range(kh):
+            for dx in range(kw):
+                acc += np.einsum("bhwc,cd->bhwd", a[:, dy:dy + ho, dx:dx + wo],
+                                 np.asarray(n.weights[dy, dx], np.int64))
+        return (acc > n.thresholds).astype(np.int64)
+    assert isinstance(n, Dense), type(n)
+    acc = a.reshape(a.shape[0], -1) @ np.asarray(n.weights, np.int64)
+    return (acc > n.thresholds).astype(np.int64) if n.step else acc - n.thresholds

@@ -46,6 +46,15 @@ The plan has four orthogonal forms:
 Backends declare which form they execute via target options
 (`pallas[packed=true]`, `pallas[planes=true]`); the Session records the
 compiled form on the `Artifact` (`artifact.plan_form`).
+
+A layer-level (conv) circuit lowers to a fifth form, "conv": layers of
+kind "conv" (a (kh, kw, c_in, c_out) kernel over the `in_shape` map),
+"pool" (a 2x2 max-pool) and "dense", each weighted layer with a
+threshold vector (`acc > t`; the argmax layer ranks `acc - t`), over an
+image of `input_shape` read in `input_mode` "compare" or "pixels". Its
+dense tail (`dense_tail`) is an ordinary dense plan whose layers carry
+thresholds, which the megakernel runs. A conv plan has no packed,
+plane or stacked form (`LayerKindError`).
 """
 from __future__ import annotations
 
@@ -54,7 +63,10 @@ from typing import Sequence
 
 import numpy as np
 
-from repro.netgen.graph import Circuit, as_layered_weights
+from repro.netgen.graph import (
+    Circuit, Conv, Dense, LayerKindError, MaxPool, TensorInput,
+    as_layered_weights, layer_kind,
+)
 
 __all__ = [
     "ExecutionPlan", "MegakernelView", "PlanLayer", "PACK_LANES",
@@ -64,8 +76,8 @@ __all__ = [
 PACK_LANES = 32      # activations per uint32 word in the packed datapath
 
 # Activation kinds a layer can apply to its accumulator vector.
-STEP = "step"        # hidden layers: strict sign step, acc > 0 -> {0,1}
-ARGMAX = "argmax"    # final layer: the class scores feed the argmax
+STEP = "step"        # hidden layers: strict step, acc > t (t = 0) -> {0,1}
+ARGMAX = "argmax"    # final layer: the class scores (acc - t) feed the argmax
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -81,6 +93,11 @@ class PlanLayer:
     the packed uint32 signed bit-planes ((P, words, fan_out), model
     axis leading when stacked) and `n_planes` the plane count P —
     `weights` stays populated as the decomposition's ground truth.
+
+    `kind` is "dense" here; a conv plan also has "conv" layers
+    (`weights` the (kh, kw, c_in, c_out) kernel) and "pool" layers (no
+    weights; `size` the window), `in_shape` the (H, W, C) map each
+    reads. `thresholds`, when set, holds one int per unit (None: all 0).
     """
     weights: np.ndarray
     activation: str
@@ -88,6 +105,10 @@ class PlanLayer:
     pos_planes: np.ndarray | None = None
     neg_planes: np.ndarray | None = None
     n_planes: int | None = None
+    kind: str = "dense"
+    thresholds: np.ndarray | None = None
+    in_shape: tuple | None = None
+    size: int | None = None
 
     @property
     def fan_in(self) -> int:
@@ -110,6 +131,8 @@ class ExecutionPlan:
     packed: bool = False
     bitplanes: bool = False          # packed + plane-decomposed weights
     n_models: int | None = None      # None: single net; M: stacked plans
+    input_shape: tuple | None = None   # conv plans: the (H, W, C) image
+    input_mode: str = "compare"        # conv plans: "compare" | "pixels"
 
     @property
     def depth(self) -> int:
@@ -120,12 +143,43 @@ class ExecutionPlan:
         return self.n_models is not None
 
     @property
+    def conv(self) -> bool:
+        """Lowered from a layer-level (ConvNet) circuit."""
+        return self.input_shape is not None
+
+    @property
     def form(self) -> str:
         """The datapath form an executor of this plan implements —
         recorded on Artifacts and shown in benchmarks."""
+        if self.conv:
+            return "conv"
         if self.bitplanes:
             return "planes"
         return "packed" if self.packed else "dense"
+
+    def require_dense(self, what: str) -> None:
+        """Raise `LayerKindError` naming `what` and the first conv or
+        pool layer when this is a conv plan."""
+        if self.conv:
+            kind = next((l.kind for l in self.layers if l.kind != "dense"), "dense")
+            raise LayerKindError(
+                f"{what} handles dense-chain plans; this plan has a {kind} "
+                "layer (a ConvNet plan)")
+
+    def dense_tail(self) -> "ExecutionPlan":
+        """The dense layers after a conv plan's last conv or pool layer,
+        as a dense plan over their {0, 1} input (threshold 0 binarizes
+        it as it is), thresholds kept: what the megakernel runs."""
+        first = max((i for i, l in enumerate(self.layers) if l.kind != "dense"),
+                    default=-1) + 1
+        layers = self.layers[first:]
+        if first == 0 and self.input_mode == "pixels":
+            raise LayerKindError("a dense first layer reads binarized inputs; "
+                                 "pixel input needs a conv first layer")
+        return ExecutionPlan(
+            n_inputs=layers[0].fan_in,
+            input_threshold=self.input_threshold if first == 0 else 0,
+            layers=tuple(dataclasses.replace(l, in_shape=None) for l in layers))
 
     @property
     def n_classes(self) -> int:
@@ -152,6 +206,7 @@ class ExecutionPlan:
         """The packed form of this plan: every layer's fan_in axis
         zero-padded to a PACK_LANES multiple so activations travel as
         uint32 words (see module doc; exact by construction)."""
+        self.require_dense("the packed form")
         if self.packed:
             return self
         layers = []
@@ -196,12 +251,18 @@ class ExecutionPlan:
         accumulator column is 0, step(0) = 0, and the padded bit lands
         in a zero-padded weight word of the next layer (zero popcount).
         The final layer's fan_out is NOT padded — `n_classes` bounds
-        the fused argmax so a phantom class can never win."""
+        the fused argmax so a phantom class can never win.
+        Layer thresholds travel as (1, N_l) int32 rows, hidden ones
+        zero-padded (a padded column is 0 and 0 > 0 is false); where
+        every threshold is 0 the view carries none, and the megakernel
+        is the one it was without them."""
+        self.require_dense("the megakernel view")
         plan = self.planes()
         if plan.n_classes < 1:
             raise ValueError("megakernel_view needs at least one class")
         depth = plan.depth
         arrays: list[np.ndarray] = []
+        thresholds: list[np.ndarray] = []
         layer_words, layer_planes, layer_fan_out = [], [], []
         want_w: int | None = None
         for i, layer in enumerate(plan.layers):
@@ -221,6 +282,10 @@ class ExecutionPlan:
                 return np.ascontiguousarray(a)
 
             arrays += [_padded(layer.pos_planes), _padded(layer.neg_planes)]
+            t = np.zeros((1, n_target), np.int32)
+            if layer.thresholds is not None:
+                t[0, :n] = layer.thresholds
+            thresholds.append(t)
             layer_words.append(w_target)
             layer_planes.append(int(layer.n_planes))
             layer_fan_out.append(n)
@@ -233,7 +298,9 @@ class ExecutionPlan:
             layer_words=tuple(layer_words),
             layer_planes=tuple(layer_planes),
             layer_fan_out=tuple(layer_fan_out),
-            arrays=tuple(arrays))
+            arrays=tuple(arrays),
+            thresholds=(tuple(thresholds) if any(t.any() for t in thresholds)
+                        else None))
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -243,7 +310,9 @@ class MegakernelView:
     word widths / plane counts / TRUE (unpadded) fan_outs, and the
     interleaved (pos_0, neg_0, pos_1, neg_1, ...) uint32 plane arrays —
     (P_l, W_l, N_l) each, leading model axis when stacked — already
-    padded so consecutive layers chain by construction."""
+    padded so consecutive layers chain by construction. `thresholds`
+    holds each layer's int32 (1, N_l) threshold row, or None where every
+    threshold is 0."""
     n_inputs: int
     input_threshold: int
     n_classes: int
@@ -252,6 +321,7 @@ class MegakernelView:
     layer_planes: tuple[int, ...]
     layer_fan_out: tuple[int, ...]
     arrays: tuple[np.ndarray, ...]
+    thresholds: tuple[np.ndarray, ...] | None = None
 
     @property
     def depth(self) -> int:
@@ -318,7 +388,45 @@ def decompose_planes(w: np.ndarray) -> tuple[np.ndarray, np.ndarray, int]:
     return pos, neg, n_planes
 
 
-_FORMS = ("dense", "packed", "planes")
+_FORMS = ("dense", "packed", "planes", "conv")
+
+
+def _lower_layers(circuit: Circuit) -> ExecutionPlan:
+    """The conv plan of a layer-level circuit: its nodes in order, each
+    reading the one before."""
+    nodes = [n for n in circuit.nodes if n.id != circuit.output]
+    head = nodes[0]
+    if not isinstance(head, TensorInput):
+        raise LayerKindError("a layer-level circuit starts at its TensorInput")
+    shape: tuple = head.shape
+    layers: list[PlanLayer] = []
+    for prev, n in zip(nodes, nodes[1:]):
+        if getattr(n, "src", None) != prev.id:
+            raise LayerKindError(f"layer node {n.id} does not read node {prev.id}: "
+                                 "a conv plan is a chain of layers")
+        if isinstance(n, Conv):
+            kh, kw, _, cout = n.weights.shape
+            layers.append(PlanLayer(
+                weights=np.asarray(n.weights, np.int32), activation=STEP, kind="conv",
+                thresholds=np.asarray(n.thresholds, np.int64), in_shape=shape))
+            shape = (shape[0] - kh + 1, shape[1] - kw + 1, cout)
+        elif isinstance(n, MaxPool):
+            layers.append(PlanLayer(
+                weights=np.zeros((0, 0), np.int32), activation=STEP, kind="pool",
+                in_shape=shape, size=n.size))
+            shape = (shape[0] // n.size, shape[1] // n.size, shape[2])
+        elif isinstance(n, Dense):
+            layers.append(PlanLayer(
+                weights=np.asarray(n.weights, np.int32),
+                activation=STEP if n.step else ARGMAX,
+                thresholds=np.asarray(n.thresholds, np.int64), in_shape=shape))
+            shape = (n.weights.shape[1],)
+        else:
+            raise LayerKindError(f"node {n.id} ({type(n).__name__}) in a conv plan")
+    return ExecutionPlan(n_inputs=circuit.n_inputs,
+                         input_threshold=circuit.input_threshold,
+                         layers=tuple(layers), input_shape=tuple(head.shape),
+                         input_mode=head.mode)
 
 
 def lower_circuit(circuit: Circuit, *, packed: bool = False,
@@ -328,11 +436,21 @@ def lower_circuit(circuit: Circuit, *, packed: bool = False,
     `form` picks the datapath ("dense" / "packed" / "planes"; the
     legacy `packed=True` flag means form="packed"). Raises
     IrregularCircuitError for shared/CSE circuits (which have no
-    layered tensor form; see `graph.as_layered_weights`)."""
+    layered tensor form; see `graph.as_layered_weights`). A layer-level
+    circuit lowers to the conv plan (form "conv", or "dense" meaning
+    the same); another form raises `LayerKindError`."""
     if form is None:
         form = "packed" if packed else "dense"
     if form not in _FORMS:
         raise ValueError(f"unknown plan form {form!r} (have {_FORMS})")
+    kind = layer_kind(circuit)
+    if kind is not None:
+        if form not in ("dense", "conv"):
+            raise LayerKindError(f"the {form} plan form handles dense chains; "
+                                 f"this circuit has a {kind} layer")
+        return _lower_layers(circuit)
+    if form == "conv":
+        raise ValueError("the conv plan form needs a layer-level circuit")
     mats = as_layered_weights(circuit)
     layers = tuple(
         PlanLayer(weights=np.asarray(w, dtype=np.int32),
@@ -358,6 +476,11 @@ def stack_plans(plans: Sequence[ExecutionPlan]) -> ExecutionPlan:
     widths changes the lane count."""
     if not plans:
         raise ValueError("no plans to stack")
+    for p in plans:
+        p.require_dense("stack_plans")
+        if any(l.thresholds is not None for l in p.layers):
+            raise LayerKindError("stack_plans stacks plans without thresholds; "
+                                 "a dense layer here has per-unit thresholds")
     if any(p.packed or p.stacked for p in plans):
         raise ValueError(
             "stack_plans takes dense single-net plans; pack after stacking")

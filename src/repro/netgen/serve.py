@@ -83,15 +83,14 @@ __all__ = [
     "cached_compile_net", "stack_layered_weights",
 ]
 
-# The most slot rounds one launch covers. Each launch pays a fixed host
-# cost, the argument transfer and the blocking fetch of its result
-# (about 1.4 ms a launch on a TPU v5e, against a kernel of 75-95 us a
-# 256-row round of the benchmark's nets), so a multi-round call is
-# served in launches of 2^k whole rounds. 64 pays that cost once per up
-# to 64 rounds, at most 1/64 of what a launch per round paid, and
-# bounds what the mechanism costs: at most 7 compiled programs per
-# version set (1, 2, 4 ... 64 rounds) and one launch's input at
-# 64 * slot_capacity * n_in bytes per version (12.8 MB at 256 x 784).
+# The most slot rounds one launch covers. Each launch pays a host cost
+# of its own (the dispatch and the blocking fetch of its result) beside
+# the transfer of its input, so a multi-round call is served in
+# launches of 2^k whole rounds. 64 pays the per-launch part once per up
+# to 64 rounds, and bounds what the mechanism costs: at most 7 compiled
+# programs per version set (1, 2, 4 ... 64 rounds) and one launch's
+# input at 64 * slot_capacity * n_in bytes per version (12.8 MB at
+# 256 x 784, 50 MB at 256 x 3,072).
 MAX_ROUNDS_PER_LAUNCH = 64
 
 

@@ -89,7 +89,8 @@ def _source_fingerprint() -> str:
         h = hashlib.sha256()
         pkg = Path(__file__).parent
         files = sorted(pkg.rglob("*.py"))
-        files.append(pkg.parent / "core" / "quantize.py")
+        files += [pkg.parent / "core" / "quantize.py",
+                  pkg.parent / "core" / "convnet.py"]
         for f in files:
             h.update(f.name.encode())
             h.update(f.read_bytes())

@@ -123,3 +123,44 @@ def test_fused_mlp_compiles_for_v5e(one_chip):
         _spec(one_chip, (N_IN, N_HIDDEN), jnp.int32),
         _spec(one_chip, (N_HIDDEN, N_OUT), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+# FINN CNV's six conv layers at its published widths, each as the conv
+# predictor launches it: (map H, W, C_in), C_out, whether a pool follows
+CNV_CONVS = [((32, 32, 3), 64, False), ((30, 30, 64), 64, True),
+             ((14, 14, 64), 128, False), ((12, 12, 128), 128, True),
+             ((5, 5, 128), 256, False), ((3, 3, 256), 256, False)]
+CNV_ROWS = 32 * BATCH                   # one 32-round launch of 256-row slots
+
+
+@pytest.mark.parametrize("shape,cout,pool", CNV_CONVS,
+                         ids=[f"{s[0]}x{s[1]}x{s[2]}-{c}" for s, c, _ in CNV_CONVS])
+def test_conv_kernel_compiles_for_v5e(one_chip, shape, cout, pool):
+    import numpy as np
+
+    from repro.kernels.binary_conv import binary_conv as bc
+
+    h, w, cin = shape
+    geo = bc.conv_geometry(h, w, cin, cout, 3, 3, pool)
+    taps = bc.banded_weights(geo, np.ones((3, 3, cin, cout), np.int64))
+    args = [_spec(one_chip, (h, CNV_ROWS, w * cin), jnp.int8),
+            _spec(one_chip, taps.shape, jnp.int8),
+            _spec(one_chip, (1, geo.block_cols), jnp.int32)]
+    if pool:
+        args.append(_spec(one_chip, bc.pool_matrix(geo).shape, jnp.int8))
+    text = _compiled_text(
+        lambda *a: bc.binary_conv(*a, geo=geo, bm=bc.batch_tile(geo), interpret=False),
+        *args)
+    assert "tpu_custom_call" in text and "netgen_conv" in text
+
+
+def test_megakernel_with_unit_thresholds_compiles_for_v5e(one_chip):
+    # CNV's dense tail, 256-512-512-10, one plane a layer
+    shapes = [(1, 8, 512), (1, 16, 512), (1, 16, 10)]
+    planes = [_spec(one_chip, s, jnp.uint32) for s in shapes for _ in ("pos", "neg")]
+    thresholds = tuple(_spec(one_chip, (1, s[-1]), jnp.int32) for s in shapes)
+    text = _compiled_text(
+        lambda x, t, *p: bmv.binary_forward_planes(
+            x, *p, threshold=0, n_classes=10, thresholds=t, interpret=False),
+        _spec(one_chip, (CNV_ROWS, 256), jnp.uint8), thresholds, *planes)
+    assert "tpu_custom_call" in text
