@@ -36,9 +36,13 @@ fusednet traffic.
 
 A fourth check (`check_rounds`) gates the split of a launch (one or
 more slot rounds): every `netgen.kernel` span has exactly one
-`netgen.round.launch` child (the predictor call) and one
-`netgen.round.fetch` child (the blocking copy of its result), the spans
-the benchmark's per-launch metrics read.
+`netgen.round.launch` child (the predictor call) and is paired with
+exactly one `netgen.round.fetch` span (the blocking copy of its
+result), the spans the benchmark's per-launch metrics read. A call's
+launches are fetched two in flight, after the next launch is
+dispatched, so a fetch is the kernel span's sibling and pairs with it
+by the `round` attr (the launch's first slot round in its call); a
+fetch nested in its kernel span pairs by nesting.
 
 A fifth check (`check_explore`) gates the design-space explorer's
 counting identities when a trace carries explorer traffic: per
@@ -238,27 +242,50 @@ def check_launches(spans: list[dict],
     return errors
 
 
-ROUND_CHILDREN = ("netgen.round.launch", "netgen.round.fetch")
-
-
 def check_rounds(spans: list[dict]) -> list[str]:
     """The split of a launch, one or more slot rounds (empty list ==
     pass): every `netgen.kernel` span parents exactly one
-    `netgen.round.launch` and one `netgen.round.fetch` span."""
-    children: dict = defaultdict(lambda: dict.fromkeys(ROUND_CHILDREN, 0))
+    `netgen.round.launch` span, and under each parent the kernel spans
+    of each `round` attr number as many as the `netgen.round.fetch`
+    spans of that round (a fetch nested in a kernel span counts as its
+    kernel's)."""
+    by_id = {rec.get("span_id"): rec for rec in spans}
+    launches: dict = defaultdict(int)
+    kernels: dict = defaultdict(int)
+    fetches: dict = defaultdict(int)
+
+    def key(rec):
+        return (rec.get("parent_id"), (rec.get("attrs") or {}).get("round"))
+
     for rec in spans:
-        if rec.get("name") in ROUND_CHILDREN:
-            children[rec.get("parent_id")][rec["name"]] += 1
+        name = rec.get("name")
+        if name == "netgen.round.launch":
+            launches[rec.get("parent_id")] += 1
+        elif name == "netgen.kernel":
+            kernels[key(rec)] += 1
+        elif name == "netgen.round.fetch":
+            parent = by_id.get(rec.get("parent_id")) or {}
+            fetches[key(parent if parent.get("name") == "netgen.kernel"
+                        else rec)] += 1
     errors: list[str] = []
     for rec in spans:
-        if rec.get("name") != "netgen.kernel":
-            continue
-        got = children[rec.get("span_id")]
-        for name in ROUND_CHILDREN:
-            if got[name] != 1:
-                errors.append(
-                    f"netgen.kernel span (span_id={rec.get('span_id')}) "
-                    f"has {got[name]} {name} children, expected exactly 1")
+        if rec.get("name") == "netgen.kernel" \
+                and launches[rec.get("span_id")] != 1:
+            errors.append(
+                f"netgen.kernel span (span_id={rec.get('span_id')}) has "
+                f"{launches[rec.get('span_id')]} netgen.round.launch "
+                f"children, expected exactly 1")
+    for (parent, rnd), n in sorted(kernels.items(), key=str):
+        if fetches[(parent, rnd)] != n:
+            errors.append(
+                f"{n} netgen.kernel span(s) of round {rnd} under span "
+                f"{parent} have {fetches[(parent, rnd)]} "
+                f"netgen.round.fetch spans, expected {n}")
+    for (parent, rnd), n in sorted(fetches.items(), key=str):
+        if (parent, rnd) not in kernels:
+            errors.append(
+                f"{n} netgen.round.fetch span(s) of round {rnd} under "
+                f"span {parent} have no netgen.kernel span")
     return errors
 
 

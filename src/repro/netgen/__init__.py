@@ -233,8 +233,11 @@ whole thing end to end. The serving path's spans split a launch (a
 run of 2^k whole slot rounds; one round for a call of at most
 `slot_capacity` rows per version) into `netgen.round.stage` (padding
 the slot block), then under `netgen.kernel` `netgen.round.launch` (the
-predictor call) and `netgen.round.fetch` (the blocking result copy);
-`netgen_slot_rounds_total` counts the rounds served; the engine's batcher
+predictor call), and `netgen.round.fetch` (the blocking result copy,
+after the call's next launch is dispatched, with the launch's `round`);
+`netgen_slot_rounds_total` counts the rounds served and
+`netgen_launches_ahead_total` the launches dispatched while an earlier
+one of the call was not yet fetched; the engine's batcher
 adds `netgen.engine.form` (waiting for and forming a batch),
 `netgen.engine.admit` (deadlines, grouping, stacking its rows) and
 `netgen.engine.resolve` (setting its futures) beside

@@ -34,7 +34,9 @@ ROADMAP's "Serving specialized programs" item:
       in `netgen_kernel_launches_total{form}`. A call of more rows
       than one slot round holds is served in few launches, each a run
       of whole slot rounds (a power of two of them, at most
-      `MAX_ROUNDS_PER_LAUNCH`), fetched once each; a call of at most
+      `MAX_ROUNDS_PER_LAUNCH` and `MAX_LAUNCH_BYTES` of input), fetched
+      once each and two in flight: launch k+1 is dispatched before the
+      host blocks on launch k's result; a call of at most
       `slot_capacity` rows per version is one round, one launch.
       When a device
       mesh with a data axis is active (`repro.parallel.sharding
@@ -79,19 +81,27 @@ from repro.serve.slots import pad_slots
 
 __all__ = [
     "CacheCounters", "CacheKey", "CacheStats", "CompileCache",
-    "DEFAULT_CACHE", "MAX_ROUNDS_PER_LAUNCH", "NetServer",
+    "DEFAULT_CACHE", "MAX_LAUNCH_BYTES", "MAX_ROUNDS_PER_LAUNCH",
+    "NetServer",
     "cached_compile_net", "stack_layered_weights",
 ]
 
-# The most slot rounds one launch covers. Each launch pays a host cost
-# of its own (the dispatch and the blocking fetch of its result) beside
-# the transfer of its input, so a multi-round call is served in
-# launches of 2^k whole rounds. 64 pays the per-launch part once per up
-# to 64 rounds, and bounds what the mechanism costs: at most 7 compiled
-# programs per version set (1, 2, 4 ... 64 rounds) and one launch's
-# input at 64 * slot_capacity * n_in bytes per version (12.8 MB at
-# 256 x 784, 50 MB at 256 x 3,072).
-MAX_ROUNDS_PER_LAUNCH = 64
+# The most slot rounds, and the most input bytes, one launch covers. A
+# multi-round call is served in launches of 2^k whole rounds, two in
+# flight (`NetServer._collect_ahead`): the input transfer of launch k+1
+# and the fetch of launch k run while a kernel runs, so a launch must be
+# short enough that a call has several, and long enough that its own
+# host cost (~0.3 ms to dispatch on a TPU v5e) stays small beside its
+# kernel. A launch's input is its uint8 block, versions x rounds *
+# slot_capacity x n_in bytes. Timed on a TPU v5e, 8,192-row calls in
+# 256-row slots at 4, 8 and 16 rounds a launch: 784-byte rows (FINN
+# LFC) ran best at 16 rounds (3.2 MB a launch; 1.65M preds/s, 1.48M at
+# 8), 3,072-byte rows (FINN CNV) at 8 (6 MiB; 870k preds/s, 827k at
+# 16). So a launch holds at most 16 rounds and 6 MiB of input (at least
+# one round): at most 5 compiled programs per version set (1, 2, 4, 8,
+# 16 rounds).
+MAX_ROUNDS_PER_LAUNCH = 16
+MAX_LAUNCH_BYTES = 6 << 20
 
 
 # ---------------------------------------------------------------------------
@@ -395,13 +405,18 @@ def _kernel_attrs(fn) -> dict:
     return attrs
 
 
-def _launch_rounds(rounds: int) -> list[int]:
-    """The launches that serve `rounds` slot rounds: powers of two of
-    at most MAX_ROUNDS_PER_LAUNCH rounds, largest first (32 -> [32],
-    33 -> [32, 1], 100 -> [64, 32, 4])."""
+def _launch_rounds(rounds: int, round_bytes: int) -> list[int]:
+    """The launches that serve `rounds` slot rounds of `round_bytes`
+    input bytes each: powers of two, largest first, of at most
+    MAX_ROUNDS_PER_LAUNCH rounds and MAX_LAUNCH_BYTES (at least one
+    round). 32 rounds of 256 x 3,072 bytes -> [8, 8, 8, 8]; of
+    256 x 784 bytes -> [16, 16]; 12 -> [8, 4]; 1 -> [1]."""
+    most = MAX_ROUNDS_PER_LAUNCH
+    while most > 1 and most * round_bytes > MAX_LAUNCH_BYTES:
+        most //= 2
     out = []
     while rounds > 0:
-        k = min(MAX_ROUNDS_PER_LAUNCH, 1 << (rounds.bit_length() - 1))
+        k = min(most, 1 << (rounds.bit_length() - 1))
         out.append(k)
         rounds -= k
     return out
@@ -459,15 +474,15 @@ class NetServer:
     Single-version requests (`predict`) route to that version's cached
     `Artifact` with fixed-capacity slot batching (the
     `repro.serve.engine` pattern — one live jit trace per model; larger
-    batches are served in launches of 2^k whole slot rounds, see
-    `MAX_ROUNDS_PER_LAUNCH`). Multi-version requests (`predict_many`) stack
-    compatible versions' ExecutionPlans into one jitted multi-net
-    dispatch — sharded over the slot dimension with `shard_map` when a
-    mesh with a data axis is active (see the module doc); incompatible
-    sets (different depth/width/classes, or a target without a multi
-    form) fall back to per-version routing. `dispatch_counts` records
-    which path served each request ("sharded" counts alongside
-    "stacked", not instead of it).
+    batches are served in launches of 2^k whole slot rounds, two in
+    flight, see `MAX_ROUNDS_PER_LAUNCH`). Multi-version requests
+    (`predict_many`) stack compatible versions' ExecutionPlans into one
+    jitted multi-net dispatch — sharded over the slot dimension with
+    `shard_map` when a mesh with a data axis is active (see the module
+    doc); incompatible sets (different depth/width/classes, or a target
+    without a multi form) fall back to per-version routing.
+    `dispatch_counts` records which path served each request
+    ("sharded" counts alongside "stacked", not instead of it).
 
     Construction: pass `session=` to compile through a `Session` (its
     memory tier and persistent store are reused; `target=`/`pipeline=`
@@ -529,6 +544,8 @@ class NetServer:
             "netgen_slot_occupancy", server=self._scope)
         self._slot_rounds = self._tel.counter(
             "netgen_slot_rounds_total", server=self._scope)
+        self._ahead = self._tel.counter(
+            "netgen_launches_ahead_total", server=self._scope)
 
     @property
     def dispatch_counts(self) -> dict:
@@ -630,14 +647,16 @@ class NetServer:
         Skewed batches do not waste rounds: the call is cut into
         segments by which versions still have requested rows, each
         segment's rounds are stacked into launches of 2^k whole rounds
-        (`_launch_rounds`), an exhausted version's padded all-zero block
+        (`_launch_rounds`), two in flight across the segments
+        (`_collect_ahead`), an exhausted version's padded all-zero block
         is never launched (it would burn kernel work and skew the
         occupancy histogram with rows nobody asked for), and the last
         remaining version finishes through the single-version slot
-        path. `netgen_predict_latency_seconds` records per-version
-        SERVICE time — the launches a version actually participated in
-        — so a 1-row version no longer inherits the whole-call latency
-        of a 4096-row co-batched one."""
+        path once the stacked launches are collected.
+        `netgen_predict_latency_seconds` records per-version SERVICE
+        time — until the last launch a version participated in was
+        collected — so a 1-row version no longer inherits the whole-call
+        latency of a 4096-row co-batched one."""
         t0 = time.perf_counter()
         names = tuple(sorted(requests))
         compiled = {v: self.compiled_for(v) for v in names}
@@ -672,36 +691,25 @@ class NetServer:
             self._dispatch["sharded"].inc()
         cap = self.slot_capacity
         ends = {v: (x.shape[0] + cap - 1) // cap for v, x in xs.items()}
-        rounds = max(ends.values())
+        # rounds [0, stop) have rows of two versions or more: stacked;
+        # the rest belong to the one longest version alone
+        *_, stop, rounds = sorted(ends.values())
         out: dict[str, list] = {v: [] for v in names}
         service = {v: 0.0 for v in names}
         with self._tel.span("netgen.dispatch",
                             path="sharded" if sharded else "stacked",
                             versions=len(names), rounds=rounds):
-            r = 0
-            while r < rounds:
-                active = tuple(v for v in names if ends[v] > r)
-                if len(active) == 1:
-                    (v,) = active
-                    t1 = time.perf_counter()
-                    out[v].append(self._run_slots(
-                        compiled[v], xs[v][r * cap:]))
-                    service[v] += time.perf_counter() - t1
-                    break
-                # a strict subset of a stackable set is itself stackable;
-                # its multi-net fn is cached in _multi like the full set's
-                afn = fn if active == names else self._stacked_fn(active)[0]
-                # every active version has rows in each round up to the
-                # first one's end: those rounds stack whole into launches
-                for k in _launch_rounds(min(ends[v] for v in active) - r):
-                    chunks = [xs[v][r * cap:(r + k) * cap] for v in active]
-                    t1 = time.perf_counter()
-                    preds, valid = self._stacked_round(afn, chunks, round=r)
-                    dt = time.perf_counter() - t1
-                    for i, v in enumerate(active):
-                        out[v].append(preds[i, :valid[i]])
-                        service[v] += dt
-                    r += k
+            t1 = time.perf_counter()
+            for preds, (active, valid) in self._collect_ahead(
+                    self._stacked_launches(fn, xs, ends, stop)):
+                dt = time.perf_counter() - t1
+                for i, v in enumerate(active):
+                    out[v].append(preds[i, :valid[i]])
+                    service[v] = dt
+            if rounds > stop:
+                (v,) = (v for v in names if ends[v] > stop)
+                out[v].append(self._run_slots(compiled[v], xs[v][stop * cap:]))
+                service[v] = time.perf_counter() - t1
         for v in names:
             self._requests(v).inc()
             self._latency(v).observe(service[v])
@@ -721,8 +729,60 @@ class NetServer:
             self._h_occupancy.observe(used / (len(valid) * cap))
         self._slot_rounds.inc(rounds)
 
-    def _stacked_round(self, fn, chunks: list, round: int = 0
-                       ) -> tuple[np.ndarray, list]:
+    def _collect_ahead(self, launches):
+        """Fetch the launches of one call, two in flight. `launches`
+        dispatches a launch at each step and yields (round, y, more,
+        meta): `round` its first slot round in the call, `y` its result
+        on the device, `more` whether another launch follows, `meta`
+        what the caller slices the result by. Launch k+1 is dispatched
+        (the next step) before the host blocks on launch k's result, so
+        the input transfer of k+1 and the fetch of k run while a kernel
+        runs; in a call of more than one launch, each result's copy to
+        the host starts as soon as it is dispatched. Yields (host
+        result, meta) in launch order, each fetched in its
+        `netgen.round.fetch` span. A call of one launch is dispatched
+        and then fetched, as it always was;
+        `netgen_launches_ahead_total` counts the launches dispatched
+        while an earlier one was not yet fetched."""
+        held = None
+        for r, y, more, meta in launches:
+            if more or held is not None:
+                y.copy_to_host_async()
+            if held is not None:
+                self._ahead.inc()
+                yield self._fetch(*held)
+            held = (r, y, meta)
+        if held is not None:
+            yield self._fetch(*held)
+
+    def _fetch(self, r: int, y, meta) -> tuple:
+        with self._tel.span("netgen.round.fetch", round=r):
+            return np.asarray(y), meta
+
+    def _stacked_launches(self, fn, xs: dict, ends: dict, stop: int):
+        """Dispatch the stacked launches of `predict_many`'s rounds
+        [0, stop), for `_collect_ahead`. The call is cut into segments
+        by which versions still have rows: every active version has
+        rows in each round up to the first one's end, so those rounds
+        stack whole into launches of 2^k rounds (`_launch_rounds`)."""
+        cap = self.slot_capacity
+        names = tuple(xs)
+        r = 0
+        while r < stop:
+            active = tuple(v for v in names if ends[v] > r)
+            # a strict subset of a stackable set is itself stackable;
+            # its multi-net fn is cached in _multi like the full set's
+            afn = fn if active == names else self._stacked_fn(active)[0]
+            # the launch's (M, rounds * cap, n_in) uint8 block
+            round_bytes = len(active) * cap * xs[active[0]].shape[1]
+            for k in _launch_rounds(min(ends[v] for v in active) - r,
+                                    round_bytes):
+                chunks = [xs[v][r * cap:(r + k) * cap] for v in active]
+                y, valid = self._stacked_round(afn, chunks, round=r)
+                yield r, y, r + k < stop, (active, valid)
+                r += k
+
+    def _stacked_round(self, fn, chunks: list, round: int = 0) -> tuple:
         """ONE stacked launch — the slot mechanics shared by
         `predict_many` and the async serving engine
         (`repro.netgen.engine`): pad each version's chunk into the
@@ -731,8 +791,9 @@ class NetServer:
         launch covers ceil(longest chunk / cap) slot rounds: one for the
         engine, which hands over at most cap rows a version, and 2^k for
         `predict_many`'s multi-round calls. `round` is the index of the
-        launch's first slot round within its call. Returns the
-        (M, rounds * cap) predictions and the per-version valid row
+        launch's first slot round within its call. Returns
+        the (M, rounds * cap) predictions, still on the device
+        (`_collect_ahead` fetches them), and the per-version valid row
         counts."""
         cap = self.slot_capacity
         rounds = -(-max(c.shape[0] for c in chunks) // cap)
@@ -748,35 +809,37 @@ class NetServer:
                  **_kernel_attrs(fn)}
         with self._tel.span("netgen.kernel", **attrs):
             with self._tel.span("netgen.round.launch"):
-                y = fn(block)
-            with self._tel.span("netgen.round.fetch"):
-                preds = np.asarray(y)        # (M, rounds * cap)
-        return preds, valid
+                return fn(block), valid
 
     def _run_slots(self, compiled: Artifact, x: np.ndarray) -> np.ndarray:
         """Serve one version's rows in launches of 2^k whole slot rounds
-        (`_launch_rounds`): a full launch passes its slice of `x` as it
-        is, only the last one is padded, and each is fetched once."""
+        (`_launch_rounds`), two in flight (`_collect_ahead`): a full
+        launch passes its slice of `x` as it is, only the last one is
+        padded, and each is fetched once."""
         _validate_batch(x, compiled.circuit.n_inputs)
-        cap = self.slot_capacity
         if x.shape[0] == 0:
             return np.zeros((0,), np.int64)
+        launches = self._slot_launches(compiled, x)
+        return np.concatenate(
+            [y[:n] for y, n in self._collect_ahead(launches)])
+
+    def _slot_launches(self, compiled: Artifact, x: np.ndarray):
+        """Dispatch `_run_slots`' launches, for `_collect_ahead`."""
+        cap = self.slot_capacity
         attrs = _kernel_attrs(getattr(compiled, "artifact", None))
-        outs = []
-        start = 0
-        for rounds in _launch_rounds(-(-x.shape[0] // cap)):
+        plan = _launch_rounds(-(-x.shape[0] // cap), cap * x[0].nbytes)
+        r = 0
+        for i, rounds in enumerate(plan):
             rows = rounds * cap
             with self._tel.span("netgen.round.stage"):
-                padded, n = pad_slots(x[start:start + rows], rows)
+                padded, n = pad_slots(x[r * cap:r * cap + rows], rows)
             self._observe_rounds([n], rounds)
-            with self._tel.span("netgen.kernel", valid=n, rounds=rounds,
-                                **attrs):
+            with self._tel.span("netgen.kernel", round=r, valid=n,
+                                rounds=rounds, **attrs):
                 with self._tel.span("netgen.round.launch"):
                     y = compiled(padded)
-                with self._tel.span("netgen.round.fetch"):
-                    outs.append(np.asarray(y)[:n])
-            start += rows
-        return np.concatenate(outs)
+            yield r, y, i + 1 < len(plan), n
+            r += rounds
 
     def _stacked_fn(self, names: tuple) -> tuple:
         """Build (or recall) the multi-net dispatch for this version set;

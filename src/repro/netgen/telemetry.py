@@ -78,11 +78,16 @@ Instrumented span tree (what a trace of one request lifecycle nests):
                             (k*cap, n_in) / (M, k*cap, n_in) block of
                             k = 2^j whole slot rounds
         netgen.kernel       one per jitted call (launch); attrs
-                            `rounds` (k), `valid`, `form`, `launches`
+                            `round` (its first slot round in the
+                            call), `rounds` (k), `valid`, `form`,
+                            `launches`
           netgen.round.launch   the predictor call until it returns
                                 (argument transfer and enqueue)
-          netgen.round.fetch    np.asarray of its result (the wait
-                                for the device, then the D2H copy)
+        netgen.round.fetch  np.asarray of a launch's result (the wait
+                            for the device, then the D2H copy); attr
+                            `round`. Launches of a call are two in
+                            flight: launch k's fetch follows launch
+                            k+1's stage and kernel spans
     netgen.engine.resolve   setting the formed batch's futures
     netgen.store.load       artifact rebuilt from disk
     netgen.tune.search      candidates, winner, measure seconds
@@ -96,7 +101,10 @@ increment per dispatch call per version — `benchmarks/check_trace.py`
 gates latency count == request count. `netgen_slot_occupancy{server}`
 takes one observation per slot round and `netgen_slot_rounds_total
 {server}` adds the slot rounds each launch covers, so its change over
-that of `netgen_kernel_launches_total` is the rounds per launch.
+that of `netgen_kernel_launches_total` is the rounds per launch;
+`netgen_launches_ahead_total{server}` counts the launches dispatched
+while an earlier launch of the same call was not yet fetched (n - 1 for
+a call of n launches).
 `netgen_kernel_launches_total{form}` counts Pallas kernel launches per
 datapath form (`kernel_launches(form)` is the accessor backends use):
 the per-layer chains record depth launches per call (times M for the

@@ -17,7 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.binary_matvec import binary_matvec as bmv
 from repro.kernels.fused_mlp import fused_mlp as fm
-from repro.netgen.serve import MAX_ROUNDS_PER_LAUNCH
+from repro.netgen.serve import MAX_LAUNCH_BYTES, MAX_ROUNDS_PER_LAUNCH
 
 N_IN, N_HIDDEN, N_OUT, PLANES = 784, 500, 10, 3
 IN_WORDS = -(-N_IN // 32)               # 25 packed input words
@@ -130,7 +130,7 @@ def test_fused_mlp_compiles_for_v5e(one_chip):
 CNV_CONVS = [((32, 32, 3), 64, False), ((30, 30, 64), 64, True),
              ((14, 14, 64), 128, False), ((12, 12, 128), 128, True),
              ((5, 5, 128), 256, False), ((3, 3, 256), 256, False)]
-CNV_ROWS = 32 * BATCH                   # one 32-round launch of 256-row slots
+CNV_ROWS = MAX_LAUNCH_BYTES // (32 * 32 * 3)  # NetServer's largest CNV launch
 
 
 @pytest.mark.parametrize("shape,cout,pool", CNV_CONVS,

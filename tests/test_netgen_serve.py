@@ -670,14 +670,16 @@ CAP = 4
 # of at most CAP rows is the one (CAP, n_in) / (M, CAP, n_in) launch
 LAUNCHES = {
     1: [1], CAP: [1], CAP + 1: [2], 3 * CAP + 5: [4, 1],
-    32 * CAP: [32], 33 * CAP: [32, 1], 100 * CAP + 1: [64, 32, 4, 1],
+    32 * CAP: [16, 16], 33 * CAP: [16, 16, 1],
+    100 * CAP + 1: [16] * 6 + [4, 1],
 }
 # rows per version -> (versions, slot rounds) of each launch: stacked
 # while every version of the launch has rows, then the remaining subset
 SKEWED = [
     ((1, 4 * CAP), [(2, 1), (1, 2), (1, 1)]),
     ((33 * CAP, 3 * CAP + 5), [(2, 4), (2, 1), (1, 16), (1, 8), (1, 4)]),
-    ((100 * CAP + 1, 32 * CAP), [(2, 32), (1, 64), (1, 4), (1, 1)]),
+    ((100 * CAP + 1, 32 * CAP),
+     [(2, 16)] * 2 + [(1, 16)] * 4 + [(1, 4), (1, 1)]),
     ((CAP + 1, 33 * CAP, 3 * CAP + 5),
      [(3, 2), (2, 2), (2, 1), (1, 16), (1, 8), (1, 4)]),
 ]
@@ -818,3 +820,185 @@ def test_fusednet_multi_round_launch_passes_the_launch_gates(
     samples = [("netgen_kernel_launches_total", {"form": "fusednet"},
                 float(telemetry.kernel_launches("fusednet").value))]
     assert check_launches(spans, samples) == []
+
+
+# ---------------------------------------------------------------------------
+# Two launches in flight: launch k+1 is dispatched before launch k is fetched
+# ---------------------------------------------------------------------------
+
+CNV_ROUND = 256 * 3072          # a 256-row slot round of 32x32x3 images
+LFC_ROUND = 256 * 784           # of 28x28 images
+
+
+@pytest.mark.parametrize("rounds, round_bytes, plan", [
+    (32, CNV_ROUND, [8, 8, 8, 8]), (12, CNV_ROUND, [8, 4]),
+    (9, CNV_ROUND, [8, 1]), (8, CNV_ROUND, [8]), (1, CNV_ROUND, [1]),
+    (32, LFC_ROUND, [16, 16]), (12, LFC_ROUND, [8, 4]),
+    # a stacked pair of 784-byte versions: 16 rounds would be 6.1 MiB
+    (32, 2 * LFC_ROUND, [8, 8, 8, 8]),
+    (40, 48, [16, 16, 8]),
+    # a round above the byte cap is still served, one round a launch
+    (3, 7 << 20, [1, 1, 1]),
+])
+def test_launch_rounds_are_greedy_powers_of_two_within_the_launch_caps(
+        rounds, round_bytes, plan):
+    from repro.netgen.serve import (MAX_LAUNCH_BYTES, MAX_ROUNDS_PER_LAUNCH,
+                                    _launch_rounds)
+
+    assert (MAX_ROUNDS_PER_LAUNCH, MAX_LAUNCH_BYTES) == (16, 6 << 20)
+    assert _launch_rounds(rounds, round_bytes) == plan
+
+
+def _ahead(server) -> int:
+    return int(telemetry.get_registry().counter(
+        "netgen_launches_ahead_total", server=server._scope).value)
+
+
+def _tiny_convnet():
+    from repro.core.convnet import ConvLayer, ConvNet, DenseLayer, PoolLayer
+
+    rng = np.random.default_rng(170)
+
+    def pm(shape):
+        return np.where(rng.standard_normal(shape) >= 0, 1, -1)
+    w1 = pm((3, 3, 3, 4))
+    return ConvNet.from_bipolar((12, 12, 3), [
+        ConvLayer(w1, 128 * w1.sum(axis=(0, 1, 2))
+                  + rng.integers(-100, 100, 4)),
+        ConvLayer(pm((3, 3, 4, 4)), rng.integers(-4, 5, 4)),
+        PoolLayer(),
+        ConvLayer(pm((3, 3, 4, 8)), rng.integers(-4, 5, 8)),
+        DenseLayer(pm((2 * 2 * 8, 10)), np.zeros(10, int))])
+
+
+# (nets, rows per version): 32 slot rounds of the longest version
+THIRTY_TWO_ROUNDS = {
+    "dense": ([_random_net(171)], [32 * CAP]),
+    "stacked-exhausted": ([_random_net(172), _random_net(173)],
+                          [32 * CAP, 20 * CAP + 3]),
+    "convnet": ([_tiny_convnet], [32 * CAP - 1]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(THIRTY_TWO_ROUNDS))
+def test_thirty_two_round_call_two_in_flight_matches_graph_evaluate(
+        monkeypatch, case):
+    """A 32-round call on the megakernel (a dense net; a stacked pair of
+    which one version runs out mid-call and the other finishes alone;
+    a conv net), in launches of 8 rounds as CNV's rows get them, gives
+    exactly `graph.evaluate`'s answers, row for row."""
+    from repro.netgen import graph
+    from repro.netgen import serve as serve_mod
+
+    monkeypatch.setattr(serve_mod, "MAX_ROUNDS_PER_LAUNCH", 8)
+    nets, rows = THIRTY_TWO_ROUNDS[case]
+    server = netgen.NetServer(target="pallas[fusednet=true]",
+                              slot_capacity=CAP, warmup=False)
+    reqs = {}
+    for i, (net, n) in enumerate(zip(nets, rows)):
+        net = net() if callable(net) else net
+        art = server.register(f"v{i}", net)
+        reqs[f"v{i}"] = _images(171 + i, n, art.circuit.n_inputs)
+    out = server.predict_many(reqs)
+    for v, x in reqs.items():
+        np.testing.assert_array_equal(
+            out[v], graph.evaluate(server.compiled_for(v).circuit, x),
+            err_msg=v)
+    assert _ahead(server) >= 3
+
+
+@pytest.mark.parametrize("rows, ahead", [
+    (1, 0), (CAP, 0), (16 * CAP, 0), (16 * CAP + 1, 1), (32 * CAP, 1),
+    (33 * CAP, 2), (64 * CAP, 3),
+])
+@pytest.mark.parametrize("versions", [1, 2])
+def test_launches_ahead_counts_all_but_the_first_launch_of_a_call(
+        rows, ahead, versions):
+    """A call served as one unbroken run of n launches dispatches n - 1
+    of them while an earlier one is not yet fetched."""
+    server = netgen.NetServer(slot_capacity=CAP, warmup=False)
+    reqs = {}
+    for i in range(versions):
+        server.register(f"v{i}", _random_net(180 + i))
+        reqs[f"v{i}"] = _images(180 + i, rows, 12)
+    server.predict_many(reqs)
+    assert _ahead(server) == ahead
+    server.predict_many(reqs)
+    assert _ahead(server) == 2 * ahead
+
+
+def test_engine_rounds_launch_nothing_ahead():
+    with netgen.ServingEngine(target="jnp", slot_capacity=CAP,
+                              max_batch_delay=0.001) as eng:
+        eng.register("v", _random_net(185))
+        futs = [eng.submit("v", x) for x in _images(185, 3 * CAP, 12)]
+        [f.result(timeout=30) for f in futs]
+        assert _ahead(eng.server) == 0
+
+
+class _Pending:
+    """A predictor's result that counts itself live until it is fetched:
+    zeros of the block's shape less its last axis."""
+
+    def __init__(self, block_shape: tuple, live: list, peak: list):
+        self.shape = block_shape[:-1]
+        self._live = live
+        live.append(self)
+        peak[0] = max(peak[0], len(live))
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, dtype=None, copy=None):
+        self._live.remove(self)
+        return np.zeros(self.shape, np.int64)
+
+
+def test_at_most_two_launches_of_a_call_are_alive(monkeypatch):
+    """Counted by a predictor stub: no launch is dispatched while two
+    are unfetched, on the single-version and the stacked path."""
+    from repro.netgen.session import Artifact
+
+    live, peak = [], [0]
+
+    def pending(*args):
+        return _Pending(args[-1].shape, live, peak)
+    server = netgen.NetServer(slot_capacity=CAP, warmup=False)
+    for v in ("a", "b"):
+        server.register(v, _random_net(190))
+    monkeypatch.setattr(Artifact, "__call__", pending)
+    monkeypatch.setattr(server, "_stacked_fn", lambda names: (pending, False))
+    x = _images(190, 40 * CAP + 1, 12)
+    assert server.predict("a", x).shape == (x.shape[0],)
+    assert peak == [2] and live == []
+    peak[0] = 0
+    out = server.predict_many({"a": x, "b": x})
+    assert out["a"].shape == out["b"].shape == (x.shape[0],)
+    assert peak == [2] and live == []
+
+
+@pytest.mark.parametrize("rows", [(33 * CAP + 3,),
+                                  (33 * CAP + 3, 20 * CAP + 1)],
+                         ids=["single", "stacked"])
+def test_slot_counts_are_those_of_one_launch_of_the_same_rows(
+        monkeypatch, rows):
+    """`netgen_slot_rounds_total` and `netgen_slot_occupancy` read the
+    same for a call split into launches of 16 rounds as for the same
+    rows in one launch."""
+    from repro.netgen import serve as serve_mod
+
+    def counts(max_rounds):
+        monkeypatch.setattr(serve_mod, "MAX_ROUNDS_PER_LAUNCH", max_rounds)
+        server = netgen.NetServer(slot_capacity=CAP, warmup=False)
+        reqs = {}
+        for i, n in enumerate(rows):
+            server.register(f"v{i}", _random_net(195 + i))
+            reqs[f"v{i}"] = _images(195 + i, n, 12)
+        server.predict_many(reqs)
+        reg = telemetry.get_registry()
+        occ = reg.histogram("netgen_slot_occupancy", server=server._scope)
+        return (reg.counter("netgen_slot_rounds_total",
+                            server=server._scope).value,
+                occ.count, occ.sum, sorted(occ._values))
+
+    assert counts(16) == counts(64)

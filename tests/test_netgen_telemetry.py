@@ -391,8 +391,9 @@ def _serve_rounds(case: str):
 @pytest.mark.parametrize("case", ["single", "stacked"])
 def test_slot_round_phases_are_profiler_events(tmp_path, case):
     """Each launch (one or more slot rounds) puts one stage, launch and
-    fetch event in the profiler's trace; launch and fetch lie inside
-    their launch's `netgen.kernel` event, stage before it."""
+    fetch event in the profiler's trace: stage before its launch's
+    `netgen.kernel` event, launch inside it, fetch after it, and, two
+    in flight, after the next launch's kernel event too."""
     work, launches = _serve_rounds(case)
     telemetry.enable()
     events = _profiled_events(tmp_path, work)
@@ -406,8 +407,9 @@ def test_slot_round_phases_are_profiler_events(tmp_path, case):
     for stage, kernel, launch, fetch in zip(stages, kernels, launches,
                                             fetches):
         assert stage[2] <= kernel[1]
-        assert kernel[1] <= launch[1] <= launch[2] <= fetch[1]
-        assert fetch[2] <= kernel[2]
+        assert kernel[1] <= launch[1] <= launch[2] <= kernel[2] <= fetch[1]
+    for kernel, fetch in zip(kernels[1:], fetches):
+        assert kernel[2] <= fetch[1]
     assert len(_names(events, "netgen.dispatch")) == 1
     spans = [r.as_dict() for r in telemetry.get_registry().spans()]
     assert check_rounds(spans) == []
@@ -666,9 +668,50 @@ def test_check_trace_gate_catches_violations(tmp_path):
     two_launches = round_ok + [rec(6, "netgen.round.launch", 3)]
     assert any("2 netgen.round.launch" in e
                for e in check_rounds(two_launches))
-    misparented = round_ok[:4] + [rec(5, "netgen.round.fetch", 1)]
+    misparented = round_ok[:4] + [rec(5, "netgen.round.fetch", 2)]
     assert any("0 netgen.round.fetch" in e
                for e in check_rounds(misparented))
     # missing files
     errors = check_trace_dir(tmp_path)
     assert any("trace.jsonl missing" in e for e in errors)
+
+
+def _two_in_flight(drop=(), fetch_round=None) -> list:
+    """Spans of one call of two launches (rounds 0 and 8) as `NetServer`
+    fetches them, two in flight: both launches dispatched, then both
+    fetched, each fetch a sibling of its kernel span with its `round`.
+    `drop` leaves out span ids; `fetch_round` relabels the last fetch."""
+    def rec(sid, name, parent=None, **attrs):
+        return {"trace_id": 1, "span_id": sid, "parent_id": parent,
+                "name": name, "start_unix": 1.0, "duration_s": 0.1,
+                "attrs": attrs, "thread": "t"}
+    spans = [rec(1, "netgen.dispatch"),
+             rec(2, "netgen.round.stage", 1),
+             rec(3, "netgen.kernel", 1, round=0, rounds=8),
+             rec(4, "netgen.round.launch", 3),
+             rec(5, "netgen.round.stage", 1),
+             rec(6, "netgen.kernel", 1, round=8, rounds=8),
+             rec(7, "netgen.round.launch", 6),
+             rec(8, "netgen.round.fetch", 1, round=0),
+             rec(9, "netgen.round.fetch", 1,
+                 round=8 if fetch_round is None else fetch_round)]
+    return [r for r in spans if r["span_id"] not in drop]
+
+
+@pytest.mark.parametrize("drop, fetch_round, error", [
+    ((), None, None),
+    ((9,), None, "1 netgen.kernel span(s) of round 8 under span 1 have 0 "
+                 "netgen.round.fetch"),
+    ((), 0, "have 2 netgen.round.fetch"),
+    ((7,), None, "0 netgen.round.launch"),
+], ids=["paired", "missing-fetch", "fetch-of-wrong-round", "missing-launch"])
+def test_check_rounds_pairs_two_in_flight_fetches_by_round(
+        drop, fetch_round, error):
+    """Launches fetched two in flight: `check_rounds` pairs each
+    `netgen.round.fetch` with its `netgen.kernel` span by the `round`
+    attr, and a planted missing or misattributed fetch fails it."""
+    errors = check_rounds(_two_in_flight(drop, fetch_round))
+    if error is None:
+        assert errors == []
+    else:
+        assert any(error in e for e in errors), errors
