@@ -1,6 +1,6 @@
 """Kernel micro-benchmarks: Pallas (interpret) vs jnp oracle timings and
 arithmetic checks. Interpret mode is Python emulation — the derived column
-reports correctness/op-counts, not TPU speed (see roofline for that).
+reports correctness/op-counts, not TPU speed (`bench/run.py` measures that).
 """
 from __future__ import annotations
 
@@ -25,7 +25,6 @@ def run(full: bool = False) -> list[str]:
     import jax.numpy as jnp
     from repro.kernels.binary_matvec import ops as bops, ref as bref
     from repro.kernels.quant_matmul import ops as qops, ref as qref
-    from repro.kernels.ssd_scan import ops as sops, ref as sref
 
     rows = []
     rng = np.random.default_rng(0)
@@ -67,17 +66,4 @@ def run(full: bool = False) -> list[str]:
     ok = int(np.allclose(np.asarray(got),
                          np.asarray(qref.quant_matmul_ref(xq, wq, np.float32(1), sw))))
     rows.append(f"kern_quant_matmul_ref,{t_ref*1e6:.1f},exact={ok}")
-
-    # ssd scan
-    b, l, h, g, p, n = (2, 256, 4, 1, 64, 128) if full else (1, 128, 2, 1, 32, 64)
-    xx = jnp.asarray(rng.normal(size=(b, l, h, p)).astype(np.float32))
-    dt = jnp.asarray(rng.uniform(0.001, 0.1, size=(b, l, h)).astype(np.float32))
-    a = jnp.asarray(-rng.uniform(0.5, 2, size=(h,)).astype(np.float32))
-    bb = jnp.asarray(rng.normal(size=(b, l, g, n)).astype(np.float32) / np.sqrt(n))
-    cc = jnp.asarray(rng.normal(size=(b, l, g, n)).astype(np.float32) / np.sqrt(n))
-    t_k = _time(lambda: sops.ssd(xx, dt, a, bb, cc, chunk=64))
-    yk, _ = sops.ssd(xx, dt, a, bb, cc, chunk=64)
-    yr, _ = sref.ssd_batched_ref(xx, dt, a, bb, cc, chunk=64)
-    err = float(np.max(np.abs(np.asarray(yk) - np.asarray(yr))))
-    rows.append(f"kern_ssd_scan_interpret,{t_k*1e6:.1f},maxerr={err:.2e}")
     return rows

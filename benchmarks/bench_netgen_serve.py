@@ -304,8 +304,7 @@ def run(full: bool = False, json_path: str | None = None) -> list[str]:
     import math
 
     import jax
-    from repro.launch.mesh import make_host_mesh
-    from repro.parallel import sharding as shd
+    from repro.netgen.mesh import make_host_mesh, use_mesh
 
     m, b = (4, 1024) if full else (2, 256)
     # the data axis must divide the slot capacity or the dispatch falls
@@ -320,7 +319,7 @@ def run(full: bool = False, json_path: str | None = None) -> list[str]:
     dt_single = _timed_mean(
         "stacked_single_device",
         lambda: shard_server.predict_many(shard_reqs), reps)
-    with shd.use_mesh(make_host_mesh(data=n_dev)):
+    with use_mesh(make_host_mesh(data=n_dev)):
         sharded_out = shard_server.predict_many(shard_reqs)  # warm
         dt_sharded = _timed_mean(
             "stacked_sharded",

@@ -99,9 +99,14 @@ def run(full: bool = False) -> list[str]:
     rows.append(f"throughput_fused_interpret,{dt*1e6:.2f},{1.0/dt:.1f}")
 
     # 5) projected TPU bound (hardware-model analogue of the paper's
-    #    500 MHz clock bound): int8 ops at MXU rate, whole net in VMEM
-    from repro.launch.mesh import HW
+    #    500 MHz clock bound): int8 ops at the v5e's published MXU rate
+    #    (the benchmark's peak table), whole net in VMEM
+    import json
+    from pathlib import Path
+    peaks = json.loads(
+        (Path(__file__).resolve().parents[1] / "bench" / "peaks.json").read_text())
+    int8_ops_per_s = peaks["devices"]["TPU v5 lite"]["int8_ops_per_s"]
     ops = 2 * (qp.w1.shape[0] * qp.w1.shape[1] + qp.w2.shape[0] * qp.w2.shape[1])
-    t_pred = ops / (2 * HW["peak_bf16_flops"])   # int8 ~ 2x bf16 rate
+    t_pred = ops / int8_ops_per_s
     rows.append(f"throughput_tpu_v5e_bound,{t_pred*1e6:.4f},{1.0/t_pred:.0f}")
     return rows

@@ -1,12 +1,12 @@
-"""Benchmark harness — one module per paper table (+ kernels & dry-run
-summary). Prints ``name,us_per_call,derived`` CSV and writes the
-repo-root ``BENCH_netgen.json`` trajectory artifact (git rev + every
-row + per-suite wall clock) so successive PRs can diff performance
-instead of re-reading CI logs.
+"""CPU rehearsal suites — one module per paper table (+ kernels). Prints
+``name,us_per_call,derived`` CSV and, with ``--bench-json FILE``, writes
+a trajectory artifact (git rev + every row + per-suite wall clock).
+These are interpret-mode CPU timings: they check that each path runs
+and agrees, and prove nothing about speed on the chip. The benchmark
+of record is ``bench/run.py`` (cells in ``BENCHMARK.json``).
 
   python -m benchmarks.run [--full] [--only SUITE] [--fake-devices N]
-      [--bench-json BENCH_netgen.json] [--serve-json FILE]
-      [--explore-report FILE]
+      [--bench-json FILE] [--serve-json FILE] [--explore-report FILE]
 
 --full runs paper-sized versions (500 hidden units, 60 epochs, full
 Verilog emission); default is a fast sanity pass. --fake-devices N
@@ -15,14 +15,13 @@ set before jax initializes, hence a flag here). --serve-json
 additionally writes the serve suite's detailed measurement dict;
 --explore-report the explore suite's ExplorationReport JSON. Suite
 artifacts are written ONLY under these declared output paths — no
-suite drops files in the working directory, so `BENCH_netgen.json`
-stays the single committed trajectory file.
+suite drops files in the working directory.
 
 Row conventions: ratio rows (`*_speedup`) put 0 in us_per_call and
 carry `ratio=..;<num>_us=..;<den>_us=..` in derived — the ratio's own
-measurement pair, self-contained in BENCH_netgen.json. The serve suite
-emits one `netgen_serve_pallas_<form>_b256` row per datapath (dense /
-packed / planes / fusednet).
+measurement pair, self-contained in the trajectory artifact. The serve
+suite emits one `netgen_serve_pallas_<form>_b256` row per datapath
+(dense / packed / planes / fusednet).
 """
 from __future__ import annotations
 
@@ -50,7 +49,7 @@ def write_bench_json(path, rows: list[str], suite_seconds: dict,
                      full: bool) -> None:
     """The perf trajectory artifact: parse the printed CSV rows into
     records and stamp them with the git revision, so a future PR can
-    diff `BENCH_netgen.json` against its parent's."""
+    diff its artifact against its parent's."""
     parsed = []
     for row in rows:
         name, _, rest = row.partition(",")
@@ -89,9 +88,9 @@ def main() -> None:
                     help="comma-separated suite names to run")
     ap.add_argument("--fake-devices", type=int, default=0, metavar="N",
                     help="fake N host devices for the sharded serving rows")
-    ap.add_argument("--bench-json", default=str(REPO_ROOT / "BENCH_netgen.json"),
-                    help="perf trajectory artifact (git rev + rows + "
-                         "timings); empty string disables")
+    ap.add_argument("--bench-json", default="",
+                    help="write the perf trajectory artifact (git rev + "
+                         "rows + timings) here; empty (default) disables")
     ap.add_argument("--serve-json", default=None,
                     help="also write the serve suite's detailed JSON here")
     ap.add_argument("--explore-report", default=None,
@@ -115,7 +114,7 @@ def main() -> None:
     from benchmarks import (bench_kernels, bench_ladder, bench_netgen,
                             bench_netgen_engine, bench_netgen_explore,
                             bench_netgen_passes, bench_netgen_serve,
-                            bench_throughput, roofline_table)
+                            bench_throughput)
 
     suites = {
         "ladder": bench_ladder.run,          # paper §III accuracy table
@@ -129,7 +128,6 @@ def main() -> None:
             store=args.store, tune_store=args.tune_store),  # joint DSE
         "throughput": bench_throughput.run,  # paper §V.E FPGA-vs-CPU table
         "kernels": bench_kernels.run,
-        "roofline": roofline_table.run,      # dry-run summary counts
     }
     print("name,us_per_call,derived")
     failed = 0

@@ -154,8 +154,7 @@ def phase_serving(session, nets, x) -> None:
 def phase_sharded(session, nets, x, n_devices: int = 4) -> None:
     import numpy as np
     from repro import netgen
-    from repro.launch.mesh import make_host_mesh
-    from repro.parallel.sharding import use_mesh
+    from repro.netgen.mesh import make_host_mesh, use_mesh
 
     print(f"== sharded: stacked {SERVE_TARGET} over a {n_devices}-device "
           "data mesh ==", flush=True)

@@ -1,4 +1,5 @@
-"""Pallas kernels of the netgen datapaths (and the LM substrate)."""
+"""Pallas kernels of the netgen datapaths (`binary_matvec`, `binary_conv`,
+`fused_mlp`) and the W8A8 `quant_matmul`, which no datapath calls yet."""
 from __future__ import annotations
 
 

@@ -1,8 +1,9 @@
 """Public ops for the W8A8 quantized matmul kernel.
 
-`qlinear` is the end-to-end op used by `repro.quantized`: quantize the
-activation on the fly (per-tensor symmetric), run the int8 kernel against
-pre-quantized weights, dequantize in the fused epilogue.
+`qlinear` is the end-to-end op: quantize the activation on the fly
+(per-tensor symmetric), run the int8 kernel against pre-quantized
+weights, dequantize in the fused epilogue. No netgen datapath calls it;
+`tests/test_kernels_quant.py` holds it to its reference.
 """
 from __future__ import annotations
 

@@ -134,7 +134,7 @@ operational: `CompileCache` is the Session's in-memory tier (same
 content addressing, LRU, thread-safe), and a `NetServer` routes request
 batches — cross-model batches of stack-compatible versions run as ONE
 jitted multi-net dispatch, and when a device mesh with a data axis is
-active (`repro.parallel.sharding.use_mesh`) that dispatch shards its
+active (`repro.netgen.mesh.use_mesh`) that dispatch shards its
 slot dimension across the mesh via `shard_map` (single-device fallback
 otherwise):
 
@@ -147,7 +147,8 @@ otherwise):
     out = server.predict_many({"v1": imgs_a, "v2": imgs_b})
     print(session.stats().row())             # hits/misses/compile time
 
-    with shd.use_mesh(make_host_mesh(data=8)):    # 8-way batch sharding
+    from repro.netgen.mesh import make_host_mesh, use_mesh
+    with use_mesh(make_host_mesh(data=8)):    # 8-way batch sharding
         out = server.predict_many({"v1": imgs_a, "v2": imgs_b})
 
 Online serving (`repro.netgen.engine`) is the async front door over

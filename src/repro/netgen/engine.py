@@ -78,7 +78,7 @@ import numpy as np
 from repro.netgen import telemetry
 from repro.netgen.serve import NetServer
 from repro.netgen.session import _validate_batch
-from repro.serve.slots import stack_requests
+from repro.netgen.slots import stack_requests
 
 __all__ = [
     "DeadlineExceededError", "EngineClosedError", "EngineStats",

@@ -18,9 +18,9 @@ ROADMAP's "Serving specialized programs" item:
       same key onto one compile, and compiles on unrelated keys never
       block each other (no head-of-line blocking).
 
-  NetServer — a multi-version predictor server in the style of
-      `repro.serve.engine`: fixed-capacity slot batching (one live jit
-      trace per model), per-request routing by version name, and
+  NetServer — a multi-version predictor server: fixed-capacity slot
+      batching (`repro.netgen.slots`; one live jit trace per model),
+      per-request routing by version name, and
       *cross-model* batching: versions whose circuits lower to
       compatible ExecutionPlans are stacked along a model axis
       (`repro.netgen.plan.stack_plans`) and served by one jitted
@@ -38,11 +38,10 @@ ROADMAP's "Serving specialized programs" item:
       once each and two in flight: launch k+1 is dispatched before the
       host blocks on launch k's result; a call of at most
       `slot_capacity` rows per version is one round, one launch.
-      When a device
-      mesh with a data axis is active (`repro.parallel.sharding
-      .use_mesh`), the stacked dispatch additionally shards its slot
-      (batch) dimension across the mesh with `shard_map` — the
-      predictions of a slot block are row-independent, so each device
+      When a device mesh with a data axis is active
+      (`repro.netgen.mesh.use_mesh`), the stacked dispatch additionally
+      shards its slot (batch) dimension across the mesh with
+      `shard_map` — the predictions of a slot block are row-independent, so each device
       serves `slot_capacity / n_data` rows of every version — and
       falls back to the single-device dispatch when no mesh is active,
       the mesh has no data axis, or the capacity does not divide.
@@ -71,13 +70,14 @@ from repro.netgen import telemetry
 from repro.netgen.backends import compile_multi
 from repro.netgen.frontend import _extract_weights
 from repro.netgen.graph import Circuit, IrregularCircuitError
+from repro.netgen.mesh import active_mesh
 from repro.netgen.pipeline import PipelineSpec
 from repro.netgen.plan import lower_circuit, stack_plans
 from repro.netgen.session import (
     Artifact, ArtifactStore, _validate_batch, artifact_key, compile_resolved,
 )
+from repro.netgen.slots import pad_slots
 from repro.netgen.targets import resolve_target, target_string
-from repro.serve.slots import pad_slots
 
 __all__ = [
     "CacheCounters", "CacheKey", "CacheStats", "CompileCache",
@@ -472,10 +472,9 @@ class NetServer:
     """Serve uint8 image batches across registered model versions.
 
     Single-version requests (`predict`) route to that version's cached
-    `Artifact` with fixed-capacity slot batching (the
-    `repro.serve.engine` pattern — one live jit trace per model; larger
-    batches are served in launches of 2^k whole slot rounds, two in
-    flight, see `MAX_ROUNDS_PER_LAUNCH`). Multi-version requests
+    `Artifact` with fixed-capacity slot batching (one live jit trace
+    per model; larger batches are served in launches of 2^k whole
+    slot rounds, two in flight, see `MAX_ROUNDS_PER_LAUNCH`). Multi-version requests
     (`predict_many`) stack compatible versions' ExecutionPlans into one
     jitted multi-net dispatch — sharded over the slot dimension with
     `shard_map` when a mesh with a data axis is active (see the module
@@ -606,7 +605,6 @@ class NetServer:
         fine or were never requested). With `names`, the report for
         that version set under the currently active mesh; without,
         {version-name tuple: report} for every diagnosed set."""
-        from repro.parallel.sharding import active_mesh
         with self._lock:
             if names is None:
                 return {k[0]: r for k, r in self._stack_reports.items()}
@@ -862,7 +860,6 @@ class NetServer:
         recorded as a `StackReport` readable through `stack_report()`
         and counted in `netgen_stack_incompat_total{reason}`."""
         from repro.netgen import analysis
-        from repro.parallel.sharding import active_mesh
 
         mesh = active_mesh()
         key = (names, mesh)

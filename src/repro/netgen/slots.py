@@ -1,12 +1,10 @@
-"""Fixed-capacity slot batching, shared across serving runtimes.
+"""Fixed-capacity slot blocks for netgen serving.
 
-Both servers in this repo batch the same way: requests are padded into
-fixed-size slot blocks so every served function sees exactly one batch
-shape and one jit trace stays live per model. The LM engine
-(`repro.serve.engine`) slots token batches; the netgen predictor server
-(`repro.netgen.serve`) slots uint8 image batches. This module holds the
-shared mechanics and deliberately depends on numpy only, so the netgen
-side can import it without pulling in the LM model stack.
+`NetServer` pads every request batch into a fixed-size slot block, so a
+served function sees exactly one batch shape and one jit trace stays live
+per model; `ServingEngine` stacks the single requests it admitted into
+the batch that `NetServer` then slots. This module holds those two
+mechanics and depends on numpy only.
 """
 from __future__ import annotations
 

@@ -19,7 +19,7 @@ from repro.core import quantize
 from repro import netgen
 from repro.netgen import telemetry
 from repro.netgen.serve import _pass_fingerprint
-from repro.serve.engine import pad_slots
+from repro.netgen.slots import pad_slots
 
 from _netgen_helpers import images, random_net
 
@@ -403,8 +403,7 @@ def test_netserver_sharded_stacked_under_mesh():
     import math
 
     import jax
-    from repro.launch.mesh import make_host_mesh
-    from repro.parallel import sharding as shd
+    from repro.netgen.mesh import make_host_mesh, use_mesh
 
     server = netgen.NetServer(slot_capacity=8, warmup=False)
     nets = {name: _random_net(110 + i) for i, name in enumerate("ab")}
@@ -415,7 +414,7 @@ def test_netserver_sharded_stacked_under_mesh():
     plain = server.predict_many({"a": x, "b": x})
     assert server.dispatch_counts["sharded"] == 0
     # a data axis that divides slot_capacity, whatever the host has
-    with shd.use_mesh(make_host_mesh(data=math.gcd(len(jax.devices()), 8))):
+    with use_mesh(make_host_mesh(data=math.gcd(len(jax.devices()), 8))):
         sharded = server.predict_many({"a": x, "b": x})
     assert server.dispatch_counts["sharded"] == 1
     assert server.dispatch_counts["stacked"] == 2
@@ -430,14 +429,13 @@ def test_netserver_sharded_stacked_under_mesh():
 def test_netserver_sharded_falls_back_without_data_axis():
     """A mesh without a data axis (or a capacity the axis cannot divide)
     must cleanly fall back to the single-device stacked dispatch."""
-    from repro.launch.mesh import make_mesh_compat
-    from repro.parallel import sharding as shd
+    from repro.netgen.mesh import make_mesh_compat, use_mesh
 
     server = netgen.NetServer(slot_capacity=8, warmup=False)
     for i, name in enumerate("ab"):
         server.register(name, _random_net(120 + i))
     x = _images(120, 8, 12)
-    with shd.use_mesh(make_mesh_compat((1,), ("model",))):
+    with use_mesh(make_mesh_compat((1,), ("model",))):
         out = server.predict_many({"a": x, "b": x})
     assert server.dispatch_counts["stacked"] == 1
     assert server.dispatch_counts["sharded"] == 0
@@ -453,8 +451,7 @@ sys.path.insert(0, {test_dir!r})
 from _netgen_helpers import random_net, images
 from repro.core import quantize
 from repro import netgen
-from repro.launch.mesh import make_host_mesh
-from repro.parallel import sharding as shd
+from repro.netgen.mesh import make_host_mesh, use_mesh
 
 assert len(jax.devices()) == 8
 nets = {{name: random_net(130 + i, (12, 9, 4), lo=-5, hi=5)
@@ -468,7 +465,7 @@ for name, net in nets.items():
 single = server.predict_many(reqs)                   # single-device path
 assert server.dispatch_counts["sharded"] == 0
 rounds = server._slot_rounds.value
-with shd.use_mesh(make_host_mesh(data=8)):           # 8-way batch sharding
+with use_mesh(make_host_mesh(data=8)):               # 8-way batch sharding
     # 19 rows a version: one launch of 2 slot rounds, (3, 32, 12) over 8
     sharded = server.predict_many(reqs)
 assert server.dispatch_counts["sharded"] == 1, server.dispatch_counts
